@@ -71,6 +71,7 @@ from repro.core import bridge, perfmodel, ref, steering
 from repro.core.control_plane import ControlPlane
 from repro.core.memport import MemPortTable
 from repro.core.topology import Topology
+from repro.launch.mesh import make_mesh
 from repro.obs import TraceRecorder, phase_op_counts
 from repro.orchestrator import Orchestrator, TenantSpec
 from repro.telemetry import TelemetryAggregator
@@ -191,23 +192,22 @@ def skewed_traffic_scenario(recorder: TraceRecorder | None = None,
     measured_pull_us = None
     if jax.device_count() >= n:
         source = f"{n}-device ring"
-        mesh = jax.make_mesh((n,), ("data",))
+        mesh = make_mesh((n,), ("data",))
         pool = jnp.zeros((n * ppn, 4), jnp.float32)
         rec = recorder if recorder is not None else TraceRecorder()
         reps = 2 if quick else 5
-        with bridge.use_mesh(mesh):
-            pull = jax.jit(lambda p, w, t: bridge.pull_pages(
-                p, w, t, mesh=mesh, budget=ROUTE_BUDGET,
-                collect_telemetry=True))
-            wj = jnp.asarray(want)
-            jax.block_until_ready(pull(pool, wj, table))   # compile
-            t0 = time.perf_counter()
-            with rec.span("transfer:skewed", scenario="skewed",
-                          rounds=rounds, reps=reps) as sp:
-                for _ in range(reps):
-                    r = pull(pool, wj, table)
-                rec.fence(r)
-            measured_pull_us = (time.perf_counter() - t0) / reps * 1e6
+        pull = jax.jit(lambda p, w, t: bridge.pull_pages(
+            p, w, t, mesh=mesh, budget=ROUTE_BUDGET,
+            collect_telemetry=True))
+        wj = jnp.asarray(want)
+        jax.block_until_ready(pull(pool, wj, table))   # compile
+        t0 = time.perf_counter()
+        with rec.span("transfer:skewed", scenario="skewed",
+                      rounds=rounds, reps=reps) as sp:
+            for _ in range(reps):
+                r = pull(pool, wj, table)
+            rec.fence(r)
+        measured_pull_us = (time.perf_counter() - t0) / reps * 1e6
         _, telem = r
         rec.annotate_telemetry(sp, telem, page_bytes=pool.shape[1] * 4)
         if samples is not None:
@@ -348,7 +348,7 @@ def pipeline_sweep(agg: TelemetryAggregator, cp: ControlPlane,
     n, ppn = ROUTE_NODES, 16
     if jax.device_count() >= n:
         out["source"] = f"{n}-device ring"
-        mesh = jax.make_mesh((n,), ("data",))
+        mesh = make_mesh((n,), ("data",))
         rng = np.random.default_rng(3)
         pool = jnp.asarray(rng.normal(size=(n * ppn, 64)).astype(np.float32))
         table = MemPortTable.striped(n * ppn, n, ppn)
@@ -361,37 +361,36 @@ def pipeline_sweep(agg: TelemetryAggregator, cp: ControlPlane,
         measured: dict = {}
         measured_unfused: dict = {}
         phase_ops: dict = {"fused": {}, "unfused": {}}
-        with bridge.use_mesh(mesh):
-            for c in PIPELINE_CHANNELS:
-                for fused, acc in ((True, measured),
-                                   (False, measured_unfused)):
-                    key = "fused" if fused else "unfused"
-                    pull = jax.jit(
-                        lambda p, w, t, _c=c, _f=fused: bridge.pull_pages(
-                            p, w, t, mesh=mesh, budget=ROUTE_BUDGET,
-                            channels=_c, fused=_f))
-                    compiled = pull.lower(pool, want, table).compile()
-                    phase_ops[key][str(c)] = phase_op_counts(
-                        compiled.as_text())
-                    jax.block_until_ready(compiled(pool, want, table))
-                    t0 = time.perf_counter()
-                    with rec.span(f"transfer:pipeline_{key}_c{c}",
-                                  scenario="pipeline", engine=key,
-                                  channels=c, reps=reps):
-                        for _ in range(reps):
-                            r = compiled(pool, want, table)
-                        rec.fence(r)
-                    acc[str(c)] = round(
-                        (time.perf_counter() - t0) / reps * 1e6, 1)
-                    if samples is not None:
-                        samples.append({
-                            "scenario": "pipeline",
-                            "name": f"pipeline_{key}_c{c}",
-                            "features": [round(float(x), 6) for x in
-                                         perfmodel.route_features(
-                                             bi, page_bytes, ROUTE_BUDGET,
-                                             rounds=rounds, channels=c)],
-                            "measured_us": acc[str(c)]})
+        for c in PIPELINE_CHANNELS:
+            for fused, acc in ((True, measured),
+                               (False, measured_unfused)):
+                key = "fused" if fused else "unfused"
+                pull = jax.jit(
+                    lambda p, w, t, _c=c, _f=fused: bridge.pull_pages(
+                        p, w, t, mesh=mesh, budget=ROUTE_BUDGET,
+                        channels=_c, fused=_f))
+                compiled = pull.lower(pool, want, table).compile()
+                phase_ops[key][str(c)] = phase_op_counts(
+                    compiled.as_text())
+                jax.block_until_ready(compiled(pool, want, table))
+                t0 = time.perf_counter()
+                with rec.span(f"transfer:pipeline_{key}_c{c}",
+                              scenario="pipeline", engine=key,
+                              channels=c, reps=reps):
+                    for _ in range(reps):
+                        r = compiled(pool, want, table)
+                    rec.fence(r)
+                acc[str(c)] = round(
+                    (time.perf_counter() - t0) / reps * 1e6, 1)
+                if samples is not None:
+                    samples.append({
+                        "scenario": "pipeline",
+                        "name": f"pipeline_{key}_c{c}",
+                        "features": [round(float(x), 6) for x in
+                                     perfmodel.route_features(
+                                         bi, page_bytes, ROUTE_BUDGET,
+                                         rounds=rounds, channels=c)],
+                        "measured_us": acc[str(c)]})
         out["measured_us_per_call"] = measured
         out["measured_unfused_us_per_call"] = measured_unfused
         out["phase_breakdown"] = _phase_breakdown(
@@ -442,7 +441,7 @@ def fused_section(quick: bool = False,
     if jax.device_count() < n:
         return out
     out["source"] = f"{n}-device ring"
-    mesh = jax.make_mesh((n,), ("data",))
+    mesh = make_mesh((n,), ("data",))
     rng = np.random.default_rng(11)
     table = MemPortTable.striped(n * ppn, n, ppn)
     want = jnp.asarray(
@@ -451,61 +450,60 @@ def fused_section(quick: bool = False,
     rec = recorder if recorder is not None else TraceRecorder()
     rounds = steering.num_rounds(want.shape[1], ROUTE_BUDGET)
     bi = steering.bidirectional_program(n)
-    with bridge.use_mesh(mesh):
-        for label, page_bytes in FUSED_PAGE_SIZES.items():
-            pool = jnp.asarray(rng.normal(
-                size=(n * ppn, page_bytes // 4)).astype(np.float32))
-            entry: dict = {"page_bytes": page_bytes}
-            pulls, times = {}, {}
-            for fused in (True, False):
-                pulls[fused] = jax.jit(
-                    lambda p, w, t, _f=fused: bridge.pull_pages(
-                        p, w, t, mesh=mesh, budget=ROUTE_BUDGET, fused=_f))
-                jax.block_until_ready(pulls[fused](pool, want, table))
-                times[fused] = []
-            with rec.span(f"transfer:fused_{label}", scenario="fused",
-                          page_bytes=page_bytes, reps=reps) as sp:
-                for rep in range(reps):
-                    order = (True, False) if rep % 2 == 0 else (False, True)
-                    for fused in order:
-                        t0 = time.perf_counter()
-                        jax.block_until_ready(
-                            pulls[fused](pool, want, table))
-                        times[fused].append(time.perf_counter() - t0)
-            entry["fused_us"] = round(
-                float(np.median(times[True])) * 1e6, 1)
-            entry["unfused_us"] = round(
-                float(np.median(times[False])) * 1e6, 1)
-            entry["speedup"] = round(entry["unfused_us"]
-                                     / max(entry["fused_us"], 1e-9), 2)
-            rec.annotate(sp, fused_us=entry["fused_us"],
-                         unfused_us=entry["unfused_us"])
-            out["page_sweep"][label] = entry
-            if samples is not None:
-                # The only samples with non-trivial wire bytes: they make
-                # the calibrator's us/MiB payload term identifiable.
-                feats = [round(float(x), 6) for x in perfmodel.route_features(
-                    bi, page_bytes, ROUTE_BUDGET, rounds=rounds)]
-                for engine in ("fused", "unfused"):
-                    samples.append({
-                        "scenario": "fused",
-                        "name": f"fused_{label}_{engine}",
-                        "features": feats,
-                        "measured_us": entry[f"{engine}_us"]})
-        # Lowered-HLO structure at the latency-bound size (where dispatch
-        # and copy overhead, not wire bytes, decide the epoch time).
+    for label, page_bytes in FUSED_PAGE_SIZES.items():
         pool = jnp.asarray(rng.normal(
-            size=(n * ppn, SMALL_PAGE_BYTES // 4)).astype(np.float32))
-        hlo = {}
-        for fused, key in ((True, "fused"), (False, "unfused")):
-            text = jax.jit(lambda p, w, t, _f=fused: bridge.pull_pages(
-                p, w, t, mesh=mesh, budget=ROUTE_BUDGET, fused=_f)).lower(
-                    pool, want, table).compile().as_text()
-            hlo[f"{key}_copies"] = hlo_analysis.count_ops(text, "copy")
-            hlo[f"{key}_collectives"] = sum(
-                hlo_analysis.count_ops(text, c)
-                for c in hlo_analysis.COLLECTIVES)
-        out["hlo"] = hlo
+            size=(n * ppn, page_bytes // 4)).astype(np.float32))
+        entry: dict = {"page_bytes": page_bytes}
+        pulls, times = {}, {}
+        for fused in (True, False):
+            pulls[fused] = jax.jit(
+                lambda p, w, t, _f=fused: bridge.pull_pages(
+                    p, w, t, mesh=mesh, budget=ROUTE_BUDGET, fused=_f))
+            jax.block_until_ready(pulls[fused](pool, want, table))
+            times[fused] = []
+        with rec.span(f"transfer:fused_{label}", scenario="fused",
+                      page_bytes=page_bytes, reps=reps) as sp:
+            for rep in range(reps):
+                order = (True, False) if rep % 2 == 0 else (False, True)
+                for fused in order:
+                    t0 = time.perf_counter()
+                    jax.block_until_ready(
+                        pulls[fused](pool, want, table))
+                    times[fused].append(time.perf_counter() - t0)
+        entry["fused_us"] = round(
+            float(np.median(times[True])) * 1e6, 1)
+        entry["unfused_us"] = round(
+            float(np.median(times[False])) * 1e6, 1)
+        entry["speedup"] = round(entry["unfused_us"]
+                                 / max(entry["fused_us"], 1e-9), 2)
+        rec.annotate(sp, fused_us=entry["fused_us"],
+                     unfused_us=entry["unfused_us"])
+        out["page_sweep"][label] = entry
+        if samples is not None:
+            # The only samples with non-trivial wire bytes: they make
+            # the calibrator's us/MiB payload term identifiable.
+            feats = [round(float(x), 6) for x in perfmodel.route_features(
+                bi, page_bytes, ROUTE_BUDGET, rounds=rounds)]
+            for engine in ("fused", "unfused"):
+                samples.append({
+                    "scenario": "fused",
+                    "name": f"fused_{label}_{engine}",
+                    "features": feats,
+                    "measured_us": entry[f"{engine}_us"]})
+    # Lowered-HLO structure at the latency-bound size (where dispatch
+    # and copy overhead, not wire bytes, decide the epoch time).
+    pool = jnp.asarray(rng.normal(
+        size=(n * ppn, SMALL_PAGE_BYTES // 4)).astype(np.float32))
+    hlo = {}
+    for fused, key in ((True, "fused"), (False, "unfused")):
+        text = jax.jit(lambda p, w, t, _f=fused: bridge.pull_pages(
+            p, w, t, mesh=mesh, budget=ROUTE_BUDGET, fused=_f)).lower(
+                pool, want, table).compile().as_text()
+        hlo[f"{key}_copies"] = hlo_analysis.count_ops(text, "copy")
+        hlo[f"{key}_collectives"] = sum(
+            hlo_analysis.count_ops(text, c)
+            for c in hlo_analysis.COLLECTIVES)
+    out["hlo"] = hlo
     return out
 
 
@@ -521,24 +519,23 @@ def _measure_composition(want, lane, table, program, n: int,
     """
     if jax.device_count() >= n:
         ppn = 16
-        mesh = jax.make_mesh((n,), ("data",))
+        mesh = make_mesh((n,), ("data",))
         pool = jnp.zeros((n * ppn, 4), jnp.float32)
         rec = recorder if recorder is not None else TraceRecorder()
-        with bridge.use_mesh(mesh):
-            pull = jax.jit(lambda p, w, t, ab, tid: bridge.pull_pages(
-                p, w, t, mesh=mesh, budget=ROUTE_BUDGET, program=program,
-                active_budget=ab, collect_telemetry=True, tenant_ids=tid))
-            args = (pool, jnp.asarray(want), table,
-                    jnp.asarray(active_budget), jnp.asarray(lane))
-            jax.block_until_ready(pull(*args))   # compile
-            t0 = time.perf_counter()
-            with rec.span(f"transfer:tenancy_{label or 'composition'}",
-                          scenario="tenancy", composition=label,
-                          reps=reps) as sp:
-                for _ in range(reps):
-                    r = pull(*args)
-                rec.fence(r)
-            dt_us = (time.perf_counter() - t0) / reps * 1e6
+        pull = jax.jit(lambda p, w, t, ab, tid: bridge.pull_pages(
+            p, w, t, mesh=mesh, budget=ROUTE_BUDGET, program=program,
+            active_budget=ab, collect_telemetry=True, tenant_ids=tid))
+        args = (pool, jnp.asarray(want), table,
+                jnp.asarray(active_budget), jnp.asarray(lane))
+        jax.block_until_ready(pull(*args))   # compile
+        t0 = time.perf_counter()
+        with rec.span(f"transfer:tenancy_{label or 'composition'}",
+                      scenario="tenancy", composition=label,
+                      reps=reps) as sp:
+            for _ in range(reps):
+                r = pull(*args)
+            rec.fence(r)
+        dt_us = (time.perf_counter() - t0) / reps * 1e6
         _, telem = r
         rec.annotate_telemetry(
             sp, telem, page_bytes=pool.shape[1] * 4,
@@ -739,18 +736,17 @@ def hierarchical_scenario(num_boards: int, board_size: int,
     bi = steering.bidirectional_program(n)
     if jax.device_count() >= n:
         source = f"{n}-device ring"
-        mesh = jax.make_mesh((n,), ("data",))
+        mesh = make_mesh((n,), ("data",))
         pool = jnp.zeros((n * ppn, 4), jnp.float32)
         rec = recorder if recorder is not None else TraceRecorder()
-        with bridge.use_mesh(mesh):
-            with rec.span(f"transfer:hierarchical_{num_boards}x{board_size}",
-                          scenario="hierarchical", boards=num_boards,
-                          board_size=board_size) as sp:
-                _, telem = bridge.pull_pages(
-                    pool, jnp.asarray(want), table, mesh=mesh,
-                    budget=ROUTE_BUDGET, topology=topo,
-                    collect_telemetry=True)
-                rec.fence(telem)
+        with rec.span(f"transfer:hierarchical_{num_boards}x{board_size}",
+                      scenario="hierarchical", boards=num_boards,
+                      board_size=board_size) as sp:
+            _, telem = bridge.pull_pages(
+                pool, jnp.asarray(want), table, mesh=mesh,
+                budget=ROUTE_BUDGET, topology=topo,
+                collect_telemetry=True)
+            rec.fence(telem)
         rec.annotate_telemetry(sp, telem, page_bytes=pool.shape[1] * 4)
     else:
         telem = ref.expected_transfer_telemetry(

@@ -85,7 +85,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import Mesh, PartitionSpec as P
+from jax.sharding import AxisType, Mesh, PartitionSpec as P
 
 from repro.core.memport import FREE, MemPortTable
 from repro.core import ref as _ref
@@ -102,35 +102,24 @@ def shard_map(f, mesh, in_specs, out_specs, mem_axis=None):
     Partial-manual mode keeps the model axis under GSPMD control inside the
     body, so head/ff dims keep their automatic sharding (and non-divisible
     head counts keep working) while the bridge runs manual collectives over
-    the mem axis.  check_vma must be True: the check_vma=False path in jax
-    0.8 rebuilds specs over *all* mesh axes and rejects partial manual.
+    the mem axis.  check_vma must be True: the check_vma=False path rebuilds
+    specs over *all* mesh axes and rejects partial manual.
+
+    The mesh must have Auto axes (:func:`repro.launch.mesh.make_mesh`):
+    with Explicit axes, shardings enter the array types and the bridge's
+    Pallas operands and index arithmetic would each need one.  The map is
+    staged through ``jax.jit`` (inlined under an outer jit): called
+    eagerly, shard_map dispatches its body op by op, which made the
+    8-device fused suite ten times slower on virtual CPU devices.
     """
+    if AxisType.Explicit in mesh.axis_types:
+        raise ValueError(
+            f"bridge meshes need Auto axes, got {mesh.axis_types}: build the "
+            "mesh with repro.launch.mesh.make_mesh")
     names = frozenset({mem_axis}) if mem_axis else frozenset(mesh.axis_names)
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, axis_names=names,
-                             check_vma=True)
-    # jax < 0.5: shard_map lives in jax.experimental and partial-manual mode
-    # (``auto``) is not usable (eager raises NotImplementedError, the jit
-    # path trips over PartitionId SPMD lowering).  Every bridge body is
-    # replicated over the non-mem axes anyway (specs never mention them), so
-    # go full-manual over all axes; replication checking (check_rep)
-    # predates VMA typing — disable it, the bridge's replicated inputs
-    # (table, program) are genuinely replicated.
-    from jax.experimental.shard_map import shard_map as _legacy_shard_map
-    return _legacy_shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_rep=False)
-
-
-def use_mesh(mesh: Mesh):
-    """Context manager installing ``mesh`` as the ambient mesh.
-
-    ``jax.set_mesh`` on current jax; on jax < 0.5 a Mesh is itself the
-    context manager.
-    """
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    return mesh
+    return jax.jit(jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                                 out_specs=out_specs, axis_names=names,
+                                 check_vma=True))
 
 
 # ---------------------------------------------------------------------------
@@ -138,13 +127,7 @@ def use_mesh(mesh: Mesh):
 # ---------------------------------------------------------------------------
 
 def _pvary(x: jax.Array, axis: str) -> jax.Array:
-    """Mark ``x`` as varying over ``axis`` (VMA typing for scan carries).
-
-    jax < 0.5 has no VMA typing (and no ``jax.lax.pcast``): no-op there.
-    Where pcast exists, real errors must surface, not be swallowed.
-    """
-    if not hasattr(jax.lax, "pcast"):
-        return x
+    """Mark ``x`` as varying over ``axis`` (VMA typing for scan carries)."""
     return jax.lax.pcast(x, axis, to="varying")
 
 
@@ -496,7 +479,6 @@ def _pull_local_fused(pool_local: jax.Array, want: jax.Array,
     lane = jnp.arange(lanes)
     sched = steering.default_route_schedule(num_nodes)
     my = jax.lax.axis_index(axis)
-    pool2, _, _e = _bg._flatten_pages(pool_local)
     exchange = _fused_exchange_mode()
 
     def body(ptr, _):
@@ -520,7 +502,7 @@ def _pull_local_fused(pool_local: jax.Array, want: jax.Array,
             reqs_by_row = jnp.full((num_nodes, lanes), FREE, jnp.int32)
             reqs_by_row = reqs_by_row.at[src_rows].set(reqs)
             with jax.named_scope("obs:gather"):
-                send = _bg.gather_pages(pool2, reqs_by_row)    # [n, lanes, e]
+                send = _bg.gather_pages(pool_local, reqs_by_row)  # [n, L, ...]
             with jax.named_scope("obs:wire_data"):
                 recv = jax.lax.all_to_all(send, axis, 0, 0)
             choice = jnp.where(dist == 0, 0, -1)
@@ -530,7 +512,7 @@ def _pull_local_fused(pool_local: jax.Array, want: jax.Array,
                 choice = jnp.where(serve, jnp.mod(my + d, num_nodes) + 1,
                                    choice)
             with jax.named_scope("obs:commit"):
-                out = _bg.pull_commit(pool2, recv, choice, loop_slot)
+                out = _bg.pull_commit(pool_local, recv, choice, loop_slot)
         else:
             # Rotation ladder: slot k's send lanes are ``reqs[k]`` verbatim
             # (what we serve for the requester d_k behind us), so each
@@ -542,10 +524,10 @@ def _pull_local_fused(pool_local: jax.Array, want: jax.Array,
             # per-slot select chain, and XLA fuses the whole tree into a
             # single output pass.
             with jax.named_scope("obs:gather"):
-                out = _bg.gather_pages(pool2, loop_slot)
+                out = _bg.gather_pages(pool_local, loop_slot)
             for k, d in enumerate(sched):
                 with jax.named_scope("obs:gather"):
-                    flit = _bg.gather_pages(pool2, reqs[k])
+                    flit = _bg.gather_pages(pool_local, reqs[k])
                 with jax.named_scope("obs:wire_data"):
                     flit = jax.lax.ppermute(
                         flit, axis,
@@ -581,18 +563,17 @@ def _push_local_fused(pool_local: jax.Array, ids: jax.Array, pay: jax.Array,
     lane = jnp.arange(lanes)
     sched = steering.default_route_schedule(num_nodes)
     my = jax.lax.axis_index(axis)
-    pool2, _, e = _bg._flatten_pages(pool_local)
-    nrows = pool2.shape[0]
-    pay2 = pay.reshape(pay.shape[0], e)
+    page_shape = pool_local.shape[1:]
+    nrows = pool_local.shape[0]
     exchange = _fused_exchange_mode()
 
     def body(carry, _):
         pool_pad, ptr = carry
         window = _fused_window(ids, ptr, budget, lanes, lane, active_budget)
-        dwin = jax.lax.dynamic_slice(pay2, (ptr, 0), (budget, e))
+        dwin = jax.lax.dynamic_slice_in_dim(pay, ptr, budget)
         if lanes > budget:
             dwin = jnp.concatenate(
-                [dwin, jnp.zeros((lanes - budget, e), pay2.dtype)])
+                [dwin, jnp.zeros((lanes - budget,) + page_shape, pay.dtype)])
         with jax.named_scope("obs:wire_req"):
             allwin = jax.lax.all_gather(window, axis)          # request flits
         src_rows, slots = _fused_steering(allwin, table, program, my,
@@ -600,7 +581,7 @@ def _push_local_fused(pool_local: jax.Array, ids: jax.Array, pay: jax.Array,
         if exchange == "a2a":
             with jax.named_scope("obs:wire_data"):
                 alldata = jax.lax.all_gather(dwin, axis)       # data flits
-            landed = alldata[src_rows]                         # [S, lanes, e]
+            landed = alldata[src_rows]                         # [S, L, ...]
         else:
             # Rotation ladder: requester j's flits for distance d land at
             # home (j + d) in one forward hop — slot k's landed data is
@@ -624,8 +605,8 @@ def _push_local_fused(pool_local: jax.Array, ids: jax.Array, pay: jax.Array,
 
     ptr0 = _pvary(jnp.int32(0), axis)
     (pool_pad, _), _ = jax.lax.scan(
-        body, (_bg.pad_pool(pool2), ptr0), None, length=rounds)
-    return pool_pad[:nrows].reshape(pool_local.shape)
+        body, (_bg.pad_pool(pool_local), ptr0), None, length=rounds)
+    return pool_pad[:nrows]
 
 
 def _push_wire(sub_ids: jax.Array, data: jax.Array, table: MemPortTable,

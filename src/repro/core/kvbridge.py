@@ -44,6 +44,7 @@ from repro.core import bridge
 from repro.core.memport import FREE, MemPortTable
 from repro.core.steering import RouteProgram
 from repro.kernels.bridge_attention import stream_decode_accumulate
+from repro.kernels.pallas_compat import resolve_interpret
 from repro.telemetry import counters as telemetry_counters
 
 NEG_INF = -1e30
@@ -267,6 +268,34 @@ def _finalize(m, l, o):
     return (o / l[..., None])
 
 
+def _node_fold(mesh: Optional[Mesh], mem_axis: str, n: int):
+    """Per-node :func:`stream_decode_accumulate` over node-major operands.
+
+    Every argument has a leading ``n`` dim (sharded over the mem axis);
+    ``seq`` holds node-local sequence indices.  A compiled Pallas kernel
+    cannot be partitioned by the compiler, so on a TPU mesh it runs inside
+    ``shard_map`` and each node folds the pages it pulled into the
+    accumulators of its own sequences.  The Pallas interpreter (off-TPU)
+    cannot run under ``shard_map``'s varying-axis checks; there one call
+    folds every node's lanes, in the same per-sequence order.
+    """
+    def fold_all(q, k, v, seq, live, m, l, o):
+        nodes, per = q.shape[:2]
+        seq = jnp.where(seq >= 0, seq + (jnp.arange(nodes) * per)[:, None],
+                        -1)
+        flat = [x.reshape((-1,) + x.shape[2:]) for x in (q, k, v)]
+        out = stream_decode_accumulate(
+            *flat, seq.reshape(-1), live.reshape(-1),
+            *(x.reshape((-1,) + x.shape[2:]) for x in (m, l, o)))
+        return tuple(x.reshape((nodes, per) + x.shape[1:]) for x in out)
+
+    if n == 1 or resolve_interpret(None):
+        return fold_all
+    spec = P(mem_axis)
+    return bridge.shard_map(fold_all, mesh, in_specs=(spec,) * 8,
+                            out_specs=(spec,) * 3, mem_axis=mem_axis)
+
+
 def decode_attention_pull(q: jax.Array, layer: PagedKVLayer,
                           table: MemPortTable, lengths: jax.Array, *,
                           page_tokens: int, max_pages: int,
@@ -334,11 +363,22 @@ def decode_attention_pull(q: jax.Array, layer: PagedKVLayer,
         # collectives (and, with no throttled active_budget, sums to
         # bit-exact telemetry: every round's spill count is zero either
         # way).
+        #
+        # Node i pulls the pages of its own sequences (request row i holds
+        # sequences [i * per_node, (i + 1) * per_node)), so it also folds
+        # them: queries and accumulators are split by node like the
+        # requests, and each node's kernel sees only its landed lanes.
         rtot = want.shape[-1]
         rounds = -(-rtot // budget)
-        m_s = jnp.full((b, h), NEG_INF, jnp.float32)
-        l_s = jnp.zeros((b, h), jnp.float32)
-        o_s = jnp.zeros((b, h, hd), jnp.float32)
+        q_n = q
+        if pad:
+            q_n = jnp.concatenate([q, jnp.zeros((pad, h, hd), q.dtype)], 0)
+        q_n = q_n.reshape(n, per_node, h, hd)
+        m_s = jnp.full((n, per_node, h), NEG_INF, jnp.float32)
+        l_s = jnp.zeros((n, per_node, h), jnp.float32)
+        o_s = jnp.zeros((n, per_node, h, hd), jnp.float32)
+        first_seq = (jnp.arange(n) * per_node)[:, None]
+        fold = _node_fold(mesh, mem_axis, n)
         for rnd in range(rounds):
             sl = slice(rnd * budget, min((rnd + 1) * budget, rtot))
             want_r = want[:, sl]
@@ -353,15 +393,13 @@ def decode_attention_pull(q: jax.Array, layer: PagedKVLayer,
                 round_t = telemetry_counters.add(telem_k, telem_v)
                 telem = (round_t if telem is None
                          else telemetry_counters.add(telem, round_t))
-            lanes = n * want_r.shape[-1]
-            wflat = want_r.reshape(-1)
-            live = wflat >= 0
+            live = want_r >= 0
             # Logical page ids encode their sequence: id // max_pages.
-            seq = jnp.where(live, wflat // max_pages, -1)
-            m_s, l_s, o_s = stream_decode_accumulate(
-                q, k_r.reshape(lanes, page_tokens, kv, hd),
-                v_r.reshape(lanes, page_tokens, kv, hd), seq, live,
-                m_s, l_s, o_s)
+            seq = jnp.where(live, want_r // max_pages - first_seq, -1)
+            m_s, l_s, o_s = fold(q_n, k_r, v_r, seq, live, m_s, l_s, o_s)
+        m_s = m_s.reshape(n * per_node, h)[:b]
+        l_s = l_s.reshape(n * per_node, h)[:b]
+        o_s = o_s.reshape(n * per_node, h, hd)[:b]
     else:
         k_pages = bridge.pull_pages(layer.k_pool, want, table,
                                     tenant_ids=tenants, **pull_kw)

@@ -32,6 +32,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.bridge_gather import out_like
 from repro.kernels.pallas_compat import resolve_interpret
 
 NEG_INF = -1e30
@@ -47,38 +48,42 @@ def _stream_kernel(seq_ref, live_ref, q_ref, k_ref, v_ref,
 
     @pl.when(i == 0)
     def _load():
-        m_sc[...] = m_in_ref[0]
-        l_sc[...] = l_in_ref[0]
-        acc_sc[...] = o_in_ref[0]
+        m_sc[...] = m_in_ref[...]
+        l_sc[...] = l_in_ref[...]
+        acc_sc[...] = o_in_ref[...]
 
     @pl.when((seq_ref[i] == b) & (live_ref[i] > 0))
     def _update():
         g = num_heads // kv_heads
         hd = q_ref.shape[-1]
-        t = k_ref.shape[1]
-        q = q_ref[0].astype(jnp.float32)                 # [H, hd]
-        k = k_ref[0].astype(jnp.float32)                 # [T, kv, hd]
-        v = v_ref[0].astype(jnp.float32)
-        qg = q.reshape(kv_heads, g, hd)
-        s = jnp.einsum("kgd,tkd->kgt", qg, k,
-                       preferred_element_type=jnp.float32) * (hd ** -0.5)
-        s = s.reshape(num_heads, t)
-        m_prev = m_sc[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1))
-        p = jnp.exp(s - m_new[:, None])
+        q = q_ref[...].astype(jnp.float32)               # [H, hd]
+        k = k_ref[...].astype(jnp.float32)               # [T * kv, hd]
+        v = v_ref[...].astype(jnp.float32)
+        # One MXU pass scores every head against every (token, kv head)
+        # row; row r of the page holds kv head r % kv, so each query head
+        # keeps the columns of its own group and masks the rest out (their
+        # probabilities are exactly 0 and add nothing to p @ v).
+        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32)
+        s = s * (hd ** -0.5)                              # [H, T * kv]
+        own = (jax.lax.broadcasted_iota(jnp.int32, s.shape, 0) // g
+               == jax.lax.broadcasted_iota(jnp.int32, s.shape, 1) % kv_heads)
+        s = jnp.where(own, s, NEG_INF)
+        m_prev = m_sc[...]                                # [H, 1]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        p = jnp.where(own, jnp.exp(s - m_new), 0.0)
         alpha = jnp.exp(m_prev - m_new)
-        l_sc[...] = l_sc[...] * alpha + jnp.sum(p, axis=1)
-        pv = jnp.einsum("kgt,tkd->kgd", p.reshape(kv_heads, g, t), v,
-                        preferred_element_type=jnp.float32)
-        acc_sc[...] = acc_sc[...] * alpha[:, None] \
-            + pv.reshape(num_heads, hd)
+        l_sc[...] = l_sc[...] * alpha + jnp.sum(p, axis=1, keepdims=True)
+        pv = jax.lax.dot_general(p, v, (((1,), (0,)), ((), ())),
+                                 preferred_element_type=jnp.float32)
+        acc_sc[...] = acc_sc[...] * alpha + pv
         m_sc[...] = m_new
 
     @pl.when(i == lanes - 1)
     def _store():
-        m_out_ref[0] = m_sc[...]
-        l_out_ref[0] = l_sc[...]
-        o_out_ref[0] = acc_sc[...]
+        m_out_ref[...] = m_sc[...]
+        l_out_ref[...] = l_sc[...]
+        o_out_ref[...] = acc_sc[...]
 
 
 def stream_decode_accumulate(q: jax.Array, k_pages: jax.Array,
@@ -92,6 +97,10 @@ def stream_decode_accumulate(q: jax.Array, k_pages: jax.Array,
     live: bool/i32[W] lane carries a real page; m, l: f32[B, H];
     o: f32[B, H, hd] running (max, denom, weighted-sum) state.
     Returns the updated ``(m, l, o)``.
+
+    Every block spans the full trailing dims of its operand, the tiling
+    Mosaic accepts at any head count: a page is viewed as its
+    ``[T * kv, hd]`` rows and the per-head statistics as ``[H, 1]`` columns.
     """
     b, h, hd = q.shape
     w, t, kv, _ = k_pages.shape
@@ -99,37 +108,45 @@ def stream_decode_accumulate(q: jax.Array, k_pages: jax.Array,
         return m, l, o
     kernel = functools.partial(_stream_kernel, lanes=w, num_heads=h,
                                kv_heads=kv)
+
+    def per_seq(bi, i, sq, lv):
+        return (bi, 0, 0)
+
+    def per_lane(bi, i, sq, lv):
+        return (i, 0, 0)
+
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(b, w),
         in_specs=[
-            pl.BlockSpec((1, h, hd), lambda bi, i, sq, lv: (bi, 0, 0)),
-            pl.BlockSpec((1, t, kv, hd), lambda bi, i, sq, lv: (i, 0, 0, 0)),
-            pl.BlockSpec((1, t, kv, hd), lambda bi, i, sq, lv: (i, 0, 0, 0)),
-            pl.BlockSpec((1, h), lambda bi, i, sq, lv: (bi, 0)),
-            pl.BlockSpec((1, h), lambda bi, i, sq, lv: (bi, 0)),
-            pl.BlockSpec((1, h, hd), lambda bi, i, sq, lv: (bi, 0, 0)),
+            pl.BlockSpec((None, h, hd), per_seq),
+            pl.BlockSpec((None, t * kv, hd), per_lane),
+            pl.BlockSpec((None, t * kv, hd), per_lane),
+            pl.BlockSpec((None, h, 1), per_seq),
+            pl.BlockSpec((None, h, 1), per_seq),
+            pl.BlockSpec((None, h, hd), per_seq),
         ],
         out_specs=[
-            pl.BlockSpec((1, h), lambda bi, i, sq, lv: (bi, 0)),
-            pl.BlockSpec((1, h), lambda bi, i, sq, lv: (bi, 0)),
-            pl.BlockSpec((1, h, hd), lambda bi, i, sq, lv: (bi, 0, 0)),
+            pl.BlockSpec((None, h, 1), per_seq),
+            pl.BlockSpec((None, h, 1), per_seq),
+            pl.BlockSpec((None, h, hd), per_seq),
         ],
         scratch_shapes=[
-            pltpu.VMEM((h,), jnp.float32),
-            pltpu.VMEM((h,), jnp.float32),
+            pltpu.VMEM((h, 1), jnp.float32),
+            pltpu.VMEM((h, 1), jnp.float32),
             pltpu.VMEM((h, hd), jnp.float32),
         ],
     )
     m2, l2, o2 = pl.pallas_call(
-        kernel, grid_spec=grid_spec,
+        kernel, grid_spec=grid_spec, name="bridge_stream_attention",
         out_shape=[
-            jax.ShapeDtypeStruct((b, h), jnp.float32),
-            jax.ShapeDtypeStruct((b, h), jnp.float32),
-            jax.ShapeDtypeStruct((b, h, hd), jnp.float32),
+            out_like((b, h, 1), jnp.float32, q, k_pages, m),
+            out_like((b, h, 1), jnp.float32, q, k_pages, m),
+            out_like((b, h, hd), jnp.float32, q, k_pages, m),
         ],
         interpret=resolve_interpret(interpret),
-    )(seq_ids.astype(jnp.int32), live.astype(jnp.int32),
-      q, k_pages, v_pages, m.astype(jnp.float32), l.astype(jnp.float32),
+    )(seq_ids.astype(jnp.int32), live.astype(jnp.int32), q,
+      k_pages.reshape(w, t * kv, hd), v_pages.reshape(w, t * kv, hd),
+      m.astype(jnp.float32)[..., None], l.astype(jnp.float32)[..., None],
       o.astype(jnp.float32))
-    return m2, l2, o2
+    return m2[..., 0], l2[..., 0], o2

@@ -25,17 +25,24 @@ transaction:
   into a sacrificial pad row — the kernel equivalent of the unfused path's
   ``mode="drop"`` scatter.
 
-All kernels flatten page contents to one trailing dim (pages move as whole
-flits; their internal layout is irrelevant to the datapath) and run through
-the shared interpret-mode policy in :mod:`repro.kernels.pallas_compat` so
-tier-1 executes them off-TPU.  Off-TPU the wrappers do NOT run the generic
-Pallas interpreter: it re-materializes the full output (and every carried
-buffer) once per grid step, which at 256 KiB pages costs more than the wire
-traffic it steers.  Instead each wrapper executes the identical block
-program as vectorized ``lax`` ops — same steering, same masked fetches,
-same sequential-grid write order (scatter shadowing is resolved explicitly,
-so duplicate commits stay deterministic) — keeping tier-1 bit-faithful to
-the TPU kernels at datapath speed.
+Pages move as whole flits, so a kernel block is always one whole page,
+viewed as a 2-D ``(sublane, lane)`` tile (:func:`_page_tiles`): a KV page
+``[T, kv, hd]`` as ``[T * kv, hd]``, a flat page of ``e`` elements as
+``[e // 128, 128]`` (or ``[1, e]`` when 128 does not divide ``e``).  The
+block then spans the full trailing dims of its operand, which Mosaic
+accepts for any page size; a ``(1, e)`` block of a ``[rows, e]`` array is
+refused (its second-minor dim is neither 8-aligned nor the array's).
+The wrappers follow the shared execution policy in
+:mod:`repro.kernels.pallas_compat`: compiled on TPU.  Off-TPU they do NOT
+run the generic Pallas interpreter: it re-materializes the full output
+(and every carried buffer) once per grid step, which at 256 KiB pages
+costs more than the wire traffic it steers.  Instead each wrapper
+executes the identical block program as vectorized ``lax`` ops — same
+steering, same masked fetches, same sequential-grid write order (scatter
+shadowing is resolved explicitly, so duplicate commits stay
+deterministic) — keeping tier-1 bit-faithful to the TPU kernels at
+datapath speed.  ``tests/test_tpu_compile.py`` checks that the kernels
+themselves compile for a TPU.
 """
 from __future__ import annotations
 
@@ -55,6 +62,37 @@ def _flatten_pages(pool: jax.Array):
     page_shape = pool.shape[1:]
     e = int(np.prod(page_shape)) if page_shape else 1
     return pool.reshape(pool.shape[0], e), page_shape, e
+
+
+def _page_tiles(page_shape) -> tuple[int, int]:
+    """The ``(sublane, lane)`` view one kernel block takes of a page."""
+    if len(page_shape) >= 2:
+        return int(np.prod(page_shape[:-1])), int(page_shape[-1])
+    e = int(np.prod(page_shape)) if page_shape else 1
+    return (e // 128, 128) if e % 128 == 0 else (1, e)
+
+
+def _tiled(x: jax.Array, lead: int, page_shape) -> jax.Array:
+    """[*lead dims, *page_shape] -> [*lead dims, sublane, lane]."""
+    return x.reshape(x.shape[:lead] + _page_tiles(page_shape))
+
+
+def out_like(shape, dtype, *operands) -> jax.ShapeDtypeStruct:
+    """A kernel output varying over every mesh axis its operands vary over
+    (inside the bridge's ``shard_map``, ``check_vma`` requires it)."""
+    vma = frozenset().union(*(jax.typeof(x).vma for x in operands))
+    return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
+
+
+def _page_spec(lead: int, page_shape, index_map) -> pl.BlockSpec:
+    """One whole page per grid step; the ``lead`` leading dims squeezed.
+
+    ``index_map`` returns the leading (row) indices only; the page's own
+    tile index is always 0.
+    """
+    def full(*args):
+        return tuple(index_map(*args)) + (0, 0)
+    return pl.BlockSpec((None,) * lead + _page_tiles(page_shape), full)
 
 
 def _obs_scope(name: str):
@@ -79,9 +117,8 @@ def _obs_scope(name: str):
 # ---------------------------------------------------------------------------
 
 def _gather_kernel(req_ref, pool_ref, out_ref):
-    w = pl.program_id(0)
-    valid = req_ref[w] >= 0
-    out_ref[0] = jnp.where(valid, pool_ref[0], jnp.zeros_like(pool_ref[0]))
+    valid = req_ref[pl.program_id(0)] >= 0
+    out_ref[...] = jnp.where(valid, pool_ref[...], jnp.zeros_like(out_ref))
 
 
 def _gather_pages_lax(pool2: jax.Array, flat: jax.Array) -> jax.Array:
@@ -112,16 +149,15 @@ def gather_pages(pool: jax.Array, reqs: jax.Array, *,
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(w,),
-        in_specs=[
-            pl.BlockSpec((1, e), lambda i, rq: (jnp.maximum(rq[i], 0), 0)),
-        ],
-        out_specs=pl.BlockSpec((1, e), lambda i, rq: (i, 0)),
+        in_specs=[_page_spec(1, page_shape,
+                             lambda i, rq: (jnp.maximum(rq[i], 0),))],
+        out_specs=_page_spec(1, page_shape, lambda i, rq: (i,)),
     )
     out = pl.pallas_call(
-        _gather_kernel, grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((w, e), pool.dtype),
-        interpret=resolve_interpret(interpret),
-    )(flat, pool2)
+        _gather_kernel, grid_spec=grid_spec, name="bridge_gather",
+        out_shape=out_like((w,) + _page_tiles(page_shape), pool.dtype,
+                            pool, flat),
+    )(flat, _tiled(pool, 1, page_shape))
     return out.reshape(shape + page_shape)
 
 
@@ -129,10 +165,10 @@ def _pull_commit_kernel(choice_ref, loop_ref, pool_ref, pay_ref, out_ref):
     i = pl.program_id(0)
     c = choice_ref[i]
     loop_ok = loop_ref[i] >= 0
-    zero = jnp.zeros_like(pool_ref[0])
-    local = jnp.where(loop_ok, pool_ref[0], zero)
-    page = jnp.where(c >= 1, pay_ref[0, 0], local)
-    out_ref[0] = jnp.where(c >= 0, page, zero)
+    zero = jnp.zeros_like(out_ref)
+    local = jnp.where(loop_ok, pool_ref[...], zero)
+    page = jnp.where(c >= 1, pay_ref[...], local)
+    out_ref[...] = jnp.where(c >= 0, page, zero)
 
 
 def _pull_commit_lax(pool2, pay2, choice, loop_slot) -> jax.Array:
@@ -169,19 +205,19 @@ def pull_commit(pool: jax.Array, payloads: jax.Array, choice: jax.Array,
         num_scalar_prefetch=2,
         grid=(lanes,),
         in_specs=[
-            pl.BlockSpec((1, e),
-                         lambda i, ch, lp: (jnp.maximum(lp[i], 0), 0)),
-            pl.BlockSpec((1, 1, e),
-                         lambda i, ch, lp: (jnp.clip(ch[i] - 1, 0, s - 1),
-                                            i, 0)),
+            _page_spec(1, page_shape,
+                       lambda i, ch, lp: (jnp.maximum(lp[i], 0),)),
+            _page_spec(2, page_shape,
+                       lambda i, ch, lp: (jnp.clip(ch[i] - 1, 0, s - 1), i)),
         ],
-        out_specs=pl.BlockSpec((1, e), lambda i, ch, lp: (i, 0)),
+        out_specs=_page_spec(1, page_shape, lambda i, ch, lp: (i,)),
     )
     out = pl.pallas_call(
-        _pull_commit_kernel, grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((lanes, e), pool.dtype),
-        interpret=resolve_interpret(interpret),
-    )(choice.astype(jnp.int32), loop_slot.astype(jnp.int32), pool2, pay2)
+        _pull_commit_kernel, grid_spec=grid_spec, name="bridge_pull_commit",
+        out_shape=out_like((lanes,) + _page_tiles(page_shape), pool.dtype,
+                            pool, payloads, choice, loop_slot),
+    )(choice.astype(jnp.int32), loop_slot.astype(jnp.int32),
+      _tiled(pool, 1, page_shape), _tiled(payloads, 2, page_shape))
     return out.reshape((lanes,) + page_shape)
 
 
@@ -191,14 +227,14 @@ def pull_commit(pool: jax.Array, payloads: jax.Array, choice: jax.Array,
 
 def pad_pool(pool: jax.Array) -> jax.Array:
     """Append the sacrificial drop row FREE pushes are steered into."""
-    return jnp.concatenate([pool, jnp.zeros_like(pool[:1])], 0)
+    return jnp.concatenate([pool, jnp.zeros((1,) + pool.shape[1:], pool.dtype)])
 
 
 def _push_commit_kernel(rows_ref, pool_ref, loop_ref, landed_ref, out_ref):
     del rows_ref, pool_ref          # steering only / aliased output init
     k = pl.program_id(1)
-    out_ref[0] = jnp.where(k == 0, loop_ref[0],
-                           landed_ref[0, 0]).astype(out_ref.dtype)
+    out_ref[...] = jnp.where(k == 0, loop_ref[...],
+                             landed_ref[...]).astype(out_ref.dtype)
 
 
 def _shadow_to(rows: jax.Array, drop_row: int) -> jax.Array:
@@ -246,49 +282,52 @@ def push_commit(pool_pad: jax.Array, slots_all: jax.Array,
                 channels: int, cb: int, interpret=None) -> jax.Array:
     """Retire one push round into the (donated) padded pool.
 
-    pool_pad: [slots + 1, E] local shard with the sacrificial drop row
-    appended (:func:`pad_pool`); returned updated, buffer aliased.
+    pool_pad: [slots + 1, *page_shape] local shard with the sacrificial
+    drop row appended (:func:`pad_pool`); returned updated, buffer aliased
+    (it stays in HBM: the kernel only writes the rows it commits).
     slots_all: i32[S + 1, L] commit rows — row 0 the epoch-0 loopback slots,
     row k+1 circuit slot k's landed slots (FREE < 0 drops).
-    loop_data: [L, E] local payloads; landed_data: [S, L, E] landed flits.
+    loop_data: [L, *page_shape] local payloads; landed_data:
+    [S, L, *page_shape] landed flits.
     L = channels * cb; the grid runs chunk-major, loopback first within each
     chunk — the serial engine's commit order, so duplicate rows resolve
     identically (sequential grid, later write wins).
     """
     slots = pool_pad.shape[0] - 1
-    e = pool_pad.shape[1]
+    page_shape = pool_pad.shape[1:]
     s1 = slots_all.shape[0]
     rows = jnp.where(slots_all >= 0, slots_all, slots).astype(jnp.int32)
     if resolve_interpret(interpret):
         return _push_commit_lax(pool_pad, rows, loop_data, landed_data,
                                 channels, cb)
 
-    def row_of(c, k, b, rw):
-        return (rw[k, c * cb + b], 0)
-
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(channels, s1, cb),
         in_specs=[
-            pl.BlockSpec((1, e), row_of),
-            pl.BlockSpec((1, e), lambda c, k, b, rw: (c * cb + b, 0)),
-            pl.BlockSpec((1, 1, e),
-                         lambda c, k, b, rw: (jnp.maximum(k - 1, 0),
-                                              c * cb + b, 0)),
+            pl.BlockSpec(memory_space=pl.ANY),
+            _page_spec(1, page_shape, lambda c, k, b, rw: (c * cb + b,)),
+            _page_spec(2, page_shape,
+                       lambda c, k, b, rw: (jnp.maximum(k - 1, 0),
+                                            c * cb + b)),
         ],
-        out_specs=pl.BlockSpec((1, e), row_of),
+        out_specs=_page_spec(1, page_shape,
+                             lambda c, k, b, rw: (rw[k, c * cb + b],)),
     )
-    return pl.pallas_call(
-        _push_commit_kernel, grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct(pool_pad.shape, pool_pad.dtype),
+    out = pl.pallas_call(
+        _push_commit_kernel, grid_spec=grid_spec, name="bridge_push_commit",
+        out_shape=out_like((slots + 1,) + _page_tiles(page_shape),
+                            pool_pad.dtype, pool_pad, rows, loop_data,
+                            landed_data),
         input_output_aliases={1: 0},
-        interpret=resolve_interpret(interpret),
-    )(rows, pool_pad, loop_data, landed_data)
+    )(rows, _tiled(pool_pad, 1, page_shape),
+      _tiled(loop_data, 1, page_shape), _tiled(landed_data, 2, page_shape))
+    return out.reshape(pool_pad.shape)
 
 
 def _scatter_kernel(rows_ref, pool_ref, data_ref, out_ref):
     del rows_ref, pool_ref
-    out_ref[0] = data_ref[0].astype(out_ref.dtype)
+    out_ref[...] = data_ref[...].astype(out_ref.dtype)
 
 
 @_obs_scope("obs:commit")
@@ -317,15 +356,16 @@ def scatter_pages(pool: jax.Array, slots: jax.Array, data: jax.Array, *,
         num_scalar_prefetch=1,
         grid=(w,),
         in_specs=[
-            pl.BlockSpec((1, e), lambda i, rw: (rw[i], 0)),
-            pl.BlockSpec((1, e), lambda i, rw: (i, 0)),
+            pl.BlockSpec(memory_space=pl.ANY),
+            _page_spec(1, page_shape, lambda i, rw: (i,)),
         ],
-        out_specs=pl.BlockSpec((1, e), lambda i, rw: (rw[i], 0)),
+        out_specs=_page_spec(1, page_shape, lambda i, rw: (rw[i],)),
     )
     out = pl.pallas_call(
-        _scatter_kernel, grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((nrows + 1, e), pool2.dtype),
+        _scatter_kernel, grid_spec=grid_spec, name="bridge_scatter",
+        out_shape=out_like((nrows + 1,) + _page_tiles(page_shape),
+                            pool.dtype, pool, rows, data),
         input_output_aliases={1: 0},
-        interpret=resolve_interpret(interpret),
-    )(rows, pad_pool(pool2), data2)
+    )(rows, _tiled(pad_pool(pool), 1, page_shape),
+      _tiled(data, 1, page_shape))
     return out[:nrows].reshape(pool.shape)

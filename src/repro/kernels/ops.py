@@ -1,10 +1,9 @@
 """Jitted public wrappers for the Pallas kernels.
 
 ``interpret=None`` resolves through the shared policy in
-:mod:`repro.kernels.pallas_compat`: interpret mode off-TPU (this container
-is CPU-only; the kernel bodies execute via the Pallas interpreter for
-correctness), native compilation on real TPU backends, overridable either
-way with ``REPRO_PALLAS_INTERPRET``.
+:mod:`repro.kernels.pallas_compat`: native compilation on a TPU backend,
+the Pallas interpreter on every other backend (the kernel bodies then
+execute as traced jax ops, which is how the CPU tests check them).
 """
 from __future__ import annotations
 

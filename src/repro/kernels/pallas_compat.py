@@ -1,37 +1,21 @@
 """Shared Pallas execution-mode policy for every kernel in the repo.
 
-All kernels run through the Pallas interpreter off-TPU (this container is
-CPU-only; interpret mode executes the kernel grid as traced jax ops, so
-tier-1 stays bit-faithful to the TPU kernel semantics) and compile natively
-on real TPU backends.  Historically each kernel wrapper re-derived this
-policy by convention; :func:`default_interpret` is the single shared source
-of truth.
-
-The environment variable ``REPRO_PALLAS_INTERPRET`` overrides the backend
-autodetection in both directions (``1/true/yes/on`` forces interpret mode,
-``0/false/no/off`` forces native compilation) — useful to smoke-test the
-native lowering path from CI without editing call sites.
+Kernels compile natively on a TPU backend and nowhere else: on any other
+backend they run in interpret mode (or, for the bridge datapath kernels,
+as their lax stand-ins — see :mod:`repro.kernels.bridge_gather`), which
+is how tier-1 checks them on CPU.  There is no override: on a TPU every
+kernel is the compiled kernel.  :func:`default_interpret` is the single
+source of truth for that choice.
 """
 from __future__ import annotations
 
-import os
 from typing import Optional
 
 import jax
 
-_TRUE = frozenset(("1", "true", "yes", "on"))
-_FALSE = frozenset(("0", "false", "no", "off"))
-
-ENV_VAR = "REPRO_PALLAS_INTERPRET"
-
 
 def default_interpret() -> bool:
-    """Interpret-mode default: env override first, then backend detection."""
-    env = os.environ.get(ENV_VAR, "").strip().lower()
-    if env in _TRUE:
-        return True
-    if env in _FALSE:
-        return False
+    """Interpret mode exactly when the default backend is not a TPU."""
     return jax.default_backend() != "tpu"
 
 

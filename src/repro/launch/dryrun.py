@@ -32,7 +32,6 @@ import jax.numpy as jnp  # noqa: E402
 from jax.sharding import NamedSharding, PartitionSpec as P  # noqa: E402
 
 from repro import configs  # noqa: E402
-from repro.core import bridge  # noqa: E402
 from repro.config import (SHAPES, BridgeConfig, RunConfig,  # noqa: E402
                           ShardingConfig)
 from repro.data.pipeline import make_batch_specs  # noqa: E402
@@ -116,10 +115,9 @@ def build_cell(arch: str, shape_name: str, *, multi_pod: bool,
         batch_abs = make_batch_specs(cfg, shape)
         b_shard = train_step_mod.batch_shardings(run, mesh, rules)
         step = train_step_mod.build_train_step(run, mesh, rules)
-        with bridge.use_mesh(mesh):
-            lowered = jax.jit(
-                step, in_shardings=(s_shard, b_shard),
-                donate_argnums=(0,)).lower(state_abs, batch_abs)
+        lowered = jax.jit(
+            step, in_shardings=(s_shard, b_shard),
+            donate_argnums=(0,)).lower(state_abs, batch_abs)
         return lowered, meta
 
     if shape.mode == "prefill":
@@ -133,10 +131,9 @@ def build_cell(arch: str, shape_name: str, *, multi_pod: bool,
             # serving prefill emits only the last position's logits
             return logits[:, -1, :]
 
-        with bridge.use_mesh(mesh):
-            lowered = jax.jit(
-                prefill, in_shardings=(p_shard, b_shard)).lower(
-                    params_abs, batch_abs)
+        lowered = jax.jit(
+            prefill, in_shardings=(p_shard, b_shard)).lower(
+                params_abs, batch_abs)
         return lowered, meta
 
     # decode
@@ -151,10 +148,9 @@ def build_cell(arch: str, shape_name: str, *, multi_pod: bool,
     step = serve_step_mod.build_serve_step(run, cache_ops)
     tok_abs = jax.ShapeDtypeStruct((b,), jnp.int32)
     tok_shard = NamedSharding(mesh, P())
-    with bridge.use_mesh(mesh):
-        lowered = jax.jit(
-            step, in_shardings=(p_shard, s_shard, tok_shard),
-            donate_argnums=(1,)).lower(params_abs, state_abs, tok_abs)
+    lowered = jax.jit(
+        step, in_shardings=(p_shard, s_shard, tok_shard),
+        donate_argnums=(1,)).lower(params_abs, state_abs, tok_abs)
     return lowered, meta
 
 

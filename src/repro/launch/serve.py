@@ -24,10 +24,12 @@ from repro.obs.clock import MonotonicClock
 
 from repro import configs
 from repro.config import RunConfig, ShapeConfig
+from repro.launch.compile_cache import enable_compile_cache
 from repro.serve import step as serve_step_mod
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--reduced", action="store_true")
@@ -176,44 +178,67 @@ def main() -> None:
                   f"https://ui.perfetto.dev)")
 
 
-def _traffic_mode(run, cfg, params, args) -> None:
-    """Request-level serving over the real jitted decode step."""
+def build_traffic_server(run, params, *, slots: int, max_len: int,
+                         page_tokens: int, seed: int, policy: str = "qos"):
+    """The request-level server: ``(batcher, engine, orchestrator)``.
+
+    A two-tenant orchestrated pool (interactive ``chat`` at share 3, batch
+    ``crawl`` at share 1) leases KV pages to a :class:`ContinuousBatcher`
+    whose slots decode through a :class:`ModelDecodeEngine` running
+    ``run``'s KV placement.
+    """
     from repro.core.control_plane import ControlPlane
     from repro.orchestrator import Orchestrator, TenantSpec
-    from repro.serve.batcher import (ContinuousBatcher, ModelDecodeEngine,
-                                     serve_loop)
-    from repro.serve.traffic import TenantTraffic, TrafficGenerator
+    from repro.serve.batcher import ContinuousBatcher, ModelDecodeEngine
 
-    slots = args.batch
-    pages_per_seq = -(-args.max_len // args.page_tokens)
+    pages_per_seq = -(-max_len // page_tokens)
     # Pool sized for the slot count (plus headroom so admission, not raw
     # capacity, is the governing control).
     cp = ControlPlane(4, slots * pages_per_seq,
-                      num_logical=4 * slots * pages_per_seq,
-                      seed=args.traffic_seed)
+                      num_logical=4 * slots * pages_per_seq, seed=seed)
     orc = Orchestrator(cp, budget=run.bridge.epoch_budget,
                        control_period=4, migrate=False)
     orc.register(TenantSpec(1, "chat", qos="interactive", share=3.0))
     orc.register(TenantSpec(2, "crawl", qos="batch", share=1.0))
     batcher = ContinuousBatcher(orc, num_slots=slots,
-                                page_tokens=args.page_tokens,
-                                policy=args.policy)
-    engine = ModelDecodeEngine(run, params, batch=slots,
-                               max_len=args.max_len, mesh=None,
-                               page_tokens=args.page_tokens,
-                               dtype=jnp.dtype(cfg.dtype))
+                                page_tokens=page_tokens, policy=policy)
+    engine = ModelDecodeEngine(run, params, batch=slots, max_len=max_len,
+                               mesh=None, page_tokens=page_tokens,
+                               dtype=jnp.dtype(run.model.dtype))
+    return batcher, engine, orc
+
+
+def make_traffic(cfg, *, prompt_max: int, output_max: int, rate: float,
+                 seed: int):
+    """Seeded Poisson arrivals of the two tenants: ``chat`` sends requests
+    a quarter of the length caps on average, ``crawl`` half of them."""
+    from repro.serve.traffic import TenantTraffic, TrafficGenerator
+    return TrafficGenerator([
+        TenantTraffic(1, rate=rate, prompt_mean=prompt_max // 4 or 1,
+                      output_mean=output_max // 4 or 1,
+                      prompt_max=prompt_max, output_max=output_max,
+                      vocab=cfg.vocab_size),
+        TenantTraffic(2, rate=rate, prompt_mean=prompt_max // 2 or 1,
+                      output_mean=output_max // 2 or 1,
+                      prompt_max=prompt_max, output_max=output_max,
+                      vocab=cfg.vocab_size),
+    ], seed=seed)
+
+
+def _traffic_mode(run, cfg, params, args) -> None:
+    """Request-level serving over the real jitted decode step."""
+    from repro.serve.batcher import serve_loop
+
+    slots = args.batch
+    batcher, engine, orc = build_traffic_server(
+        run, params, slots=slots, max_len=args.max_len,
+        page_tokens=args.page_tokens, seed=args.traffic_seed,
+        policy=args.policy)
     # Lengths cap: a sequence's prompt + output must fit max_len.
     pmax = max(args.max_len // 2, 2)
-    omax = max(args.max_len - pmax, 1)
-    traffic = TrafficGenerator([
-        TenantTraffic(1, rate=args.traffic_rate, prompt_mean=pmax // 4 or 1,
-                      output_mean=omax // 4 or 1, prompt_max=pmax,
-                      output_max=omax, vocab=cfg.vocab_size),
-        TenantTraffic(2, rate=args.traffic_rate,
-                      prompt_mean=pmax // 2 or 1, output_mean=omax // 2 or 1,
-                      prompt_max=pmax, output_max=omax,
-                      vocab=cfg.vocab_size),
-    ], seed=args.traffic_seed)
+    traffic = make_traffic(cfg, prompt_max=pmax,
+                           output_max=max(args.max_len - pmax, 1),
+                           rate=args.traffic_rate, seed=args.traffic_seed)
 
     wall = MonotonicClock()
     t0 = wall.now_us()

@@ -21,10 +21,12 @@ from repro.obs.clock import MonotonicClock
 from repro.checkpoint import CheckpointManager
 from repro.config import OptimConfig, RunConfig, ShapeConfig
 from repro.data.pipeline import Prefetcher, SyntheticLM
+from repro.launch.compile_cache import enable_compile_cache
 from repro.train import step as train_step_mod
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--reduced", action="store_true",

@@ -62,7 +62,8 @@ def pipeline_apply(stage_fn: Callable[[Any, jax.Array], jax.Array],
             buf_next = jax.lax.ppermute(y, stage_axis, perm=fwd)
             return (buf_next, outs), None
 
-        buf0 = bridge._pvary(jnp.zeros_like(x_local[0]), stage_axis)
+        buf0 = bridge._pvary(jnp.zeros(x_local.shape[1:], x_local.dtype),
+                            stage_axis)
         outs0 = bridge._pvary(jnp.zeros_like(x_local), stage_axis)
         (_, outs), _ = jax.lax.scan(
             tick, (buf0, outs0), jnp.arange(ticks))

@@ -141,8 +141,17 @@ class ModelDecodeEngine:
                                         page_tokens=page_tokens, **kw)
         self.params = params
         self.state = init_serve_state(run, batch, self.cache_ops)
-        self._step = jax.jit(build_serve_step(run, self.cache_ops))
+        # The state is donated: each step replaces it, so the KV pools are
+        # updated in place instead of held twice.
+        self._step = jax.jit(build_serve_step(run, self.cache_ops),
+                             donate_argnums=(1,))
         self._jnp = jnp
+
+    def lower(self):
+        """The decode step lowered for this engine's params and state
+        (``.compile()`` it to inspect the program the engine runs)."""
+        tokens = self._jnp.zeros((self.num_slots,), self._jnp.int32)
+        return self._step.lower(self.params, self.state, tokens)
 
     def step(self, tokens: np.ndarray,
              reset: Sequence[int] = ()) -> np.ndarray:

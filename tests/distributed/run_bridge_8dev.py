@@ -21,6 +21,7 @@ from repro.core.memport import FREE, MemPortTable  # noqa: E402
 from repro.core.control_plane import ControlPlane  # noqa: E402
 from repro.core.topology import Topology  # noqa: E402
 from repro.telemetry import TelemetryAggregator  # noqa: E402
+from repro.launch.mesh import make_mesh  # noqa: E402
 
 TELEM_FIELDS = ("slot_served", "loopback_served", "spilled", "pruned",
                 "traffic", "epoch_cw", "epoch_ccw", "slot_intra",
@@ -36,12 +37,12 @@ def check(name, got, exp, atol=1e-5):
 
 def main():
     assert jax.device_count() == 8, jax.devices()
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = make_mesh((4, 2), ("data", "model"))
     n, ppn, page = 4, 8, 16
     rng = np.random.default_rng(0)
     pool = jnp.asarray(rng.normal(size=(n * ppn, page)).astype(np.float32))
 
-    with jax.set_mesh(mesh) if hasattr(jax, "set_mesh") else mesh:
+    with jax.set_mesh(mesh):
         # --- pull: striped placement, every node asks across the ring -------
         table = MemPortTable.striped(24, n, ppn)
         want = rng.integers(-1, 24, size=(n, 7)).astype(np.int32)
@@ -202,7 +203,7 @@ def route_program_checks():
     * the bidirectional program covers all 7 distances in 8 // 2 = 4
       circuit epochs (vs 7 unidirectionally).
     """
-    mesh8 = jax.make_mesh((8,), ("data",))
+    mesh8 = make_mesh((8,), ("data",))
     n, ppn, page = 8, 8, 16
     rng = np.random.default_rng(7)
     pool = jnp.asarray(rng.normal(size=(n * ppn, page)).astype(np.float32))
@@ -271,7 +272,7 @@ def telemetry_checks():
     * a throttled push spills exactly the tail the rate limiter drops,
     * counters feed the aggregator and compile a load-balanced program.
     """
-    mesh8 = jax.make_mesh((8,), ("data",))
+    mesh8 = make_mesh((8,), ("data",))
     n, ppn, page = 8, 8, 16
     rng = np.random.default_rng(11)
     pool = jnp.asarray(rng.normal(size=(n * ppn, page)).astype(np.float32))
@@ -356,7 +357,7 @@ def hierarchical_checks():
     * a topology-aware control plane compiles a valid hierarchical program
       from placement.
     """
-    mesh8 = jax.make_mesh((8,), ("data",))
+    mesh8 = make_mesh((8,), ("data",))
     topo = Topology.boards(2, 4)
     n, ppn, page = 8, 8, 16
     rng = np.random.default_rng(23)
@@ -459,7 +460,7 @@ def pipelined_checks():
       (historically the pull chain skipped it: N-2), the edge-buffered
       datapath has none.
     """
-    mesh8 = jax.make_mesh((8,), ("data",))
+    mesh8 = make_mesh((8,), ("data",))
     n, ppn, page = 8, 8, 16
     rng = np.random.default_rng(31)
     pool = jnp.asarray(rng.normal(size=(n * ppn, page)).astype(np.float32))
@@ -481,97 +482,96 @@ def pipelined_checks():
         ("masked", steering.masked_ranks_program(hier, mask)),
     ]
 
-    with bridge.use_mesh(mesh8):
-        # One jitted pull/push per depth; programs stay runtime inputs, so
-        # the whole variant sweep compiles each engine exactly once.
-        pulls = {ch: jax.jit(functools.partial(
-            bridge.pull_pages, mesh=mesh8, budget=3, channels=ch,
-            topology=topo, collect_telemetry=True)) for ch in (1, 2, 4)}
-        pushes = {ch: jax.jit(functools.partial(
-            bridge.push_pages, mesh=mesh8, budget=2, channels=ch))
-            for ch in (1, 2, 4)}
-        dest = np.stack([np.arange(4) + 6 * node for node in range(n)])
-        payload = rng.normal(size=(n, 4, page)).astype(np.float32)
-        for name, prog in variants:
-            serial, telem_s = pulls[1](pool, want, table, program=prog)
-            pserial = pushes[1](pool, jnp.asarray(dest),
-                                jnp.asarray(payload), table, program=prog)
-            for ch in (2, 4):
-                piped, telem_p = pulls[ch](pool, want, table, program=prog)
-                np.testing.assert_array_equal(
-                    np.asarray(piped), np.asarray(serial),
-                    err_msg=f"pull {name} ch={ch}")
-                for f in TELEM_FIELDS:
-                    np.testing.assert_array_equal(
-                        np.asarray(getattr(telem_p, f)),
-                        np.asarray(getattr(telem_s, f)),
-                        err_msg=f"telemetry {name} ch={ch}: {f}")
-                exp = ref.pull_pages_pipelined_ref(
-                    pool, want, table, ppn, prog, budget=3, channels=ch)
-                np.testing.assert_array_equal(np.asarray(piped),
-                                              np.asarray(exp),
-                                              err_msg=f"oracle {name} {ch}")
-                ppiped = pushes[ch](pool, jnp.asarray(dest),
-                                    jnp.asarray(payload), table,
-                                    program=prog)
-                np.testing.assert_array_equal(
-                    np.asarray(ppiped), np.asarray(pserial),
-                    err_msg=f"push {name} ch={ch}")
-            print(f"ok: pipelined pull+push {name} bit-exact "
-                  f"(ch=2,4 + oracle)")
-
-        # throttled + overprovisioned pipelined pull keeps spill semantics
-        want3 = jnp.asarray(np.arange(32).reshape(8, 4).astype(np.int32))
-        table32 = MemPortTable.striped(32, n, ppn)
+    # One jitted pull/push per depth; programs stay runtime inputs, so
+    # the whole variant sweep compiles each engine exactly once.
+    pulls = {ch: jax.jit(functools.partial(
+        bridge.pull_pages, mesh=mesh8, budget=3, channels=ch,
+        topology=topo, collect_telemetry=True)) for ch in (1, 2, 4)}
+    pushes = {ch: jax.jit(functools.partial(
+        bridge.push_pages, mesh=mesh8, budget=2, channels=ch))
+        for ch in (1, 2, 4)}
+    dest = np.stack([np.arange(4) + 6 * node for node in range(n)])
+    payload = rng.normal(size=(n, 4, page)).astype(np.float32)
+    for name, prog in variants:
+        serial, telem_s = pulls[1](pool, want, table, program=prog)
+        pserial = pushes[1](pool, jnp.asarray(dest),
+                            jnp.asarray(payload), table, program=prog)
         for ch in (2, 4):
-            got = jax.jit(functools.partial(
-                bridge.pull_pages, mesh=mesh8, budget=4, overprovision=2,
-                channels=ch))(pool, want3, table32,
-                              active_budget=jnp.int32(2))
+            piped, telem_p = pulls[ch](pool, want, table, program=prog)
+            np.testing.assert_array_equal(
+                np.asarray(piped), np.asarray(serial),
+                err_msg=f"pull {name} ch={ch}")
+            for f in TELEM_FIELDS:
+                np.testing.assert_array_equal(
+                    np.asarray(getattr(telem_p, f)),
+                    np.asarray(getattr(telem_s, f)),
+                    err_msg=f"telemetry {name} ch={ch}: {f}")
             exp = ref.pull_pages_pipelined_ref(
-                pool, want3, table32, ppn, None, budget=4, channels=ch,
-                active_budget=2, overprovision=2)
-            np.testing.assert_array_equal(np.asarray(got), np.asarray(exp))
-        print("ok: pipelined pull throttled/overprovisioned == oracle")
+                pool, want, table, ppn, prog, budget=3, channels=ch)
+            np.testing.assert_array_equal(np.asarray(piped),
+                                          np.asarray(exp),
+                                          err_msg=f"oracle {name} {ch}")
+            ppiped = pushes[ch](pool, jnp.asarray(dest),
+                                jnp.asarray(payload), table,
+                                program=prog)
+            np.testing.assert_array_equal(
+                np.asarray(ppiped), np.asarray(pserial),
+                err_msg=f"push {name} ch={ch}")
+        print(f"ok: pipelined pull+push {name} bit-exact "
+              f"(ch=2,4 + oracle)")
 
-        # channels swaps retrace (static knob) but never change results;
-        # programs still swap retrace-free at any depth
-        for ch in (2, 4):
-            assert pulls[ch]._cache_size() == 1, pulls[ch]._cache_size()
-            assert pushes[ch]._cache_size() == 1, pushes[ch]._cache_size()
-        print("ok: program swaps retrace-free at channels=2,4")
+    # throttled + overprovisioned pipelined pull keeps spill semantics
+    want3 = jnp.asarray(np.arange(32).reshape(8, 4).astype(np.int32))
+    table32 = MemPortTable.striped(32, n, ppn)
+    for ch in (2, 4):
+        got = jax.jit(functools.partial(
+            bridge.pull_pages, mesh=mesh8, budget=4, overprovision=2,
+            channels=ch))(pool, want3, table32,
+                          active_budget=jnp.int32(2))
+        exp = ref.pull_pages_pipelined_ref(
+            pool, want3, table32, ppn, None, budget=4, channels=ch,
+            active_budget=2, overprovision=2)
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(exp))
+    print("ok: pipelined pull throttled/overprovisioned == oracle")
 
-        # HLO regression: bufferless serialization barriers (incl. loopback)
-        def barriers(f, *args):
-            return jax.jit(f).lower(*args).as_text().count(
-                "optimization_barrier")
+    # channels swaps retrace (static knob) but never change results;
+    # programs still swap retrace-free at any depth
+    for ch in (2, 4):
+        assert pulls[ch]._cache_size() == 1, pulls[ch]._cache_size()
+        assert pushes[ch]._cache_size() == 1, pushes[ch]._cache_size()
+    print("ok: program swaps retrace-free at channels=2,4")
 
-        pull_nb = functools.partial(bridge.pull_pages, mesh=mesh8, budget=3,
-                                    edge_buffer=False)
-        push_nb = functools.partial(bridge.push_pages, mesh=mesh8, budget=2,
-                                    edge_buffer=False)
-        assert barriers(pull_nb, pool, want, table) == n - 1
-        assert barriers(push_nb, pool, jnp.asarray(dest),
-                        jnp.asarray(payload), table) == n - 1
-        pull_eb = functools.partial(bridge.pull_pages, mesh=mesh8, budget=3)
-        assert barriers(pull_eb, pool, want, table) == 0
-        # bufferless results identical on both paths (serialization only)
-        got_nb = bridge.pull_pages(pool, want, table, mesh=mesh8, budget=3,
-                                   edge_buffer=False, channels=4)
-        np.testing.assert_array_equal(
-            np.asarray(got_nb),
-            np.asarray(ref.pull_pages_ref(pool, want, table,
-                                          pages_per_node=ppn)))
-        got_pb = bridge.push_pages(pool, jnp.asarray(dest),
-                                   jnp.asarray(payload), table, mesh=mesh8,
-                                   budget=2, edge_buffer=False)
-        np.testing.assert_array_equal(
-            np.asarray(got_pb),
-            np.asarray(ref.push_pages_ref(pool, jnp.asarray(dest),
-                                          jnp.asarray(payload), table,
-                                          pages_per_node=ppn)))
-        print("ok: bufferless barriers = N-1 (loopback chained), results "
-              "bit-exact")
+    # HLO regression: bufferless serialization barriers (incl. loopback)
+    def barriers(f, *args):
+        return jax.jit(f).lower(*args).as_text().count(
+            "optimization_barrier")
+
+    pull_nb = functools.partial(bridge.pull_pages, mesh=mesh8, budget=3,
+                                edge_buffer=False)
+    push_nb = functools.partial(bridge.push_pages, mesh=mesh8, budget=2,
+                                edge_buffer=False)
+    assert barriers(pull_nb, pool, want, table) == n - 1
+    assert barriers(push_nb, pool, jnp.asarray(dest),
+                    jnp.asarray(payload), table) == n - 1
+    pull_eb = functools.partial(bridge.pull_pages, mesh=mesh8, budget=3)
+    assert barriers(pull_eb, pool, want, table) == 0
+    # bufferless results identical on both paths (serialization only)
+    got_nb = bridge.pull_pages(pool, want, table, mesh=mesh8, budget=3,
+                               edge_buffer=False, channels=4)
+    np.testing.assert_array_equal(
+        np.asarray(got_nb),
+        np.asarray(ref.pull_pages_ref(pool, want, table,
+                                      pages_per_node=ppn)))
+    got_pb = bridge.push_pages(pool, jnp.asarray(dest),
+                               jnp.asarray(payload), table, mesh=mesh8,
+                               budget=2, edge_buffer=False)
+    np.testing.assert_array_equal(
+        np.asarray(got_pb),
+        np.asarray(ref.push_pages_ref(pool, jnp.asarray(dest),
+                                      jnp.asarray(payload), table,
+                                      pages_per_node=ppn)))
+    print("ok: bufferless barriers = N-1 (loopback chained), results "
+          "bit-exact")
 
 
 if __name__ == "__main__":

@@ -24,6 +24,7 @@ from repro.config import OptimConfig, RunConfig, ShapeConfig  # noqa: E402
 from repro.data.pipeline import SyntheticLM  # noqa: E402
 from repro.optim import compress as C  # noqa: E402
 from repro.train import step as train_step_mod  # noqa: E402
+from repro.launch.mesh import make_mesh  # noqa: E402
 
 
 def test_ring_allreduce(mesh):
@@ -57,30 +58,29 @@ def test_compressed_training(mesh):
         base, optim=dataclasses.replace(base.optim, compress_grads=True))
 
     data = SyntheticLM(cfg, 8, 32)
-    with bridge.use_mesh(mesh):
-        state_p = train_step_mod.make_train_state(base, jax.random.key(0))
-        state_c = train_step_mod.make_train_state(comp, jax.random.key(0),
-                                                  compress=True, dp_size=4)
-        from repro.parallel.sharding import make_rules
-        rules = make_rules(base.sharding, mesh, global_batch=8)
-        step_p = jax.jit(train_step_mod.build_train_step(base, mesh, rules))
-        step_c = jax.jit(train_step_mod.build_train_step(comp, mesh, rules))
+    state_p = train_step_mod.make_train_state(base, jax.random.key(0))
+    state_c = train_step_mod.make_train_state(comp, jax.random.key(0),
+                                              compress=True, dp_size=4)
+    from repro.parallel.sharding import make_rules
+    rules = make_rules(base.sharding, mesh, global_batch=8)
+    step_p = jax.jit(train_step_mod.build_train_step(base, mesh, rules))
+    step_c = jax.jit(train_step_mod.build_train_step(comp, mesh, rules))
 
-        lowered = jax.jit(
-            train_step_mod.build_train_step(comp, mesh, rules)).lower(
-            state_c, {k: jnp.asarray(v) for k, v in data.batch_at(0).items()})
-        hlo = lowered.compile().as_text()
-        assert "s8[" in hlo and "collective-permute" in hlo, \
-            "int8 wire traffic missing from compressed step"
-        print("ok: s8 collective-permute traffic present in HLO")
+    lowered = jax.jit(
+        train_step_mod.build_train_step(comp, mesh, rules)).lower(
+        state_c, {k: jnp.asarray(v) for k, v in data.batch_at(0).items()})
+    hlo = lowered.compile().as_text()
+    assert "s8[" in hlo and "collective-permute" in hlo, \
+        "int8 wire traffic missing from compressed step"
+    print("ok: s8 collective-permute traffic present in HLO")
 
-        losses_p, losses_c = [], []
-        for i in range(8):
-            batch = {k: jnp.asarray(v) for k, v in data.batch_at(i).items()}
-            state_p, mp = step_p(state_p, batch)
-            state_c, mc = step_c(state_c, batch)
-            losses_p.append(float(mp["loss"]))
-            losses_c.append(float(mc["loss"]))
+    losses_p, losses_c = [], []
+    for i in range(8):
+        batch = {k: jnp.asarray(v) for k, v in data.batch_at(i).items()}
+        state_p, mp = step_p(state_p, batch)
+        state_c, mc = step_c(state_c, batch)
+        losses_p.append(float(mp["loss"]))
+        losses_c.append(float(mc["loss"]))
     print("plain:", [round(x, 4) for x in losses_p])
     print("compressed:", [round(x, 4) for x in losses_c])
     assert losses_c[-1] < losses_c[0], "compressed training diverged"
@@ -91,7 +91,7 @@ def test_compressed_training(mesh):
 
 def main():
     assert jax.device_count() == 8
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = make_mesh((4, 2), ("data", "model"))
     test_ring_allreduce(mesh)
     test_compressed_training(mesh)
     print("ALL OK")

@@ -27,6 +27,7 @@ import numpy as np  # noqa: E402
 from repro.core import bridge, ref, steering  # noqa: E402
 from repro.core.memport import MemPortTable  # noqa: E402
 from repro.core.topology import Topology  # noqa: E402
+from repro.launch.mesh import make_mesh  # noqa: E402
 
 TELEM_FIELDS = ("slot_served", "loopback_served", "spilled", "pruned",
                 "traffic", "epoch_cw", "epoch_ccw", "slot_intra",
@@ -50,7 +51,7 @@ def check_telem(name, got, exp):
 
 def main():
     assert jax.device_count() == 8, jax.devices()
-    mesh = jax.make_mesh((8,), ("data",))
+    mesh = make_mesh((8,), ("data",))
     n, ppn, page = 8, 8, 4
     rng = np.random.default_rng(7)
     pool = jnp.asarray(rng.normal(size=(n * ppn, page)).astype(np.float32))
@@ -77,7 +78,7 @@ def main():
         "default": None,
     }
 
-    with jax.set_mesh(mesh) if hasattr(jax, "set_mesh") else mesh:
+    with jax.set_mesh(mesh):
         # fused vs the numpy page oracles: six variants x channels {1,2,4}
         # (one trace per channels — programs swap as runtime inputs)
         for name, prog in variants.items():

@@ -40,6 +40,7 @@ from repro.core.topology import Topology  # noqa: E402
 from repro.obs import (ManualClock, MetricsRegistry,  # noqa: E402
                        TraceRecorder, phase_op_counts)
 from repro.telemetry import TelemetryAggregator  # noqa: E402
+from repro.launch.mesh import make_mesh  # noqa: E402
 
 N, PPN, PAGE = 8, 8, 16
 TENANT_NAMES = {0: "t0", 1: "t1", 2: "t2", 3: "t3"}
@@ -65,7 +66,7 @@ def variants(topo):
 def span_reconciliation_checks():
     """Real-telemetry span args == oracle-telemetry span args, bit-exact,
     and the registry's counter families agree with both."""
-    mesh8 = jax.make_mesh((8,), ("data",))
+    mesh8 = make_mesh((8,), ("data",))
     rng = np.random.default_rng(41)
     pool = jnp.asarray(rng.normal(size=(N * PPN, PAGE)).astype(np.float32))
     table = MemPortTable.striped(48, N, PPN)
@@ -76,58 +77,57 @@ def span_reconciliation_checks():
     page_bytes = PAGE * 4
 
     rec = TraceRecorder(ManualClock(), process_name="obs-8dev")
-    with bridge.use_mesh(mesh8):
-        pull = jax.jit(functools.partial(
-            bridge.pull_pages, mesh=mesh8, budget=3, topology=topo,
-            collect_telemetry=True))
-        for name, prog in variants(topo):
-            with rec.span(f"transfer:{name}", variant=name,
-                          budget=3) as sp:
-                out, telem = pull(pool, want, table, program=prog,
-                                  active_budget=ab, tenant_ids=lane)
-                rec.fence((out, telem))
-            rec.annotate_telemetry(sp, telem, page_bytes=page_bytes,
-                                   tenant_names=TENANT_NAMES)
+    pull = jax.jit(functools.partial(
+        bridge.pull_pages, mesh=mesh8, budget=3, topology=topo,
+        collect_telemetry=True))
+    for name, prog in variants(topo):
+        with rec.span(f"transfer:{name}", variant=name,
+                      budget=3) as sp:
+            out, telem = pull(pool, want, table, program=prog,
+                              active_budget=ab, tenant_ids=lane)
+            rec.fence((out, telem))
+        rec.annotate_telemetry(sp, telem, page_bytes=page_bytes,
+                               tenant_names=TENANT_NAMES)
 
-            exp = ref.expected_transfer_telemetry(
-                np.asarray(want), table, prog, num_nodes=N, budget=3,
-                topology=topo, active_budget=np.asarray(ab),
-                tenant_ids=np.asarray(lane))
-            with rec.span(f"oracle:{name}", variant=name) as sp_exp:
-                pass
-            rec.annotate_telemetry(sp_exp, exp, page_bytes=page_bytes,
-                                   tenant_names=TENANT_NAMES)
-            counters = {k: v for k, v in sp.args.items()
-                        if k not in ("variant", "budget")}
-            counters_exp = {k: v for k, v in sp_exp.args.items()
-                            if k != "variant"}
-            assert counters == counters_exp, (
-                f"{name}: span counters diverge from oracle\n"
-                f"real:   {counters}\noracle: {counters_exp}")
-            assert counters["pages_served"] > 0, f"{name}: nothing served"
+        exp = ref.expected_transfer_telemetry(
+            np.asarray(want), table, prog, num_nodes=N, budget=3,
+            topology=topo, active_budget=np.asarray(ab),
+            tenant_ids=np.asarray(lane))
+        with rec.span(f"oracle:{name}", variant=name) as sp_exp:
+            pass
+        rec.annotate_telemetry(sp_exp, exp, page_bytes=page_bytes,
+                               tenant_names=TENANT_NAMES)
+        counters = {k: v for k, v in sp.args.items()
+                    if k not in ("variant", "budget")}
+        counters_exp = {k: v for k, v in sp_exp.args.items()
+                        if k != "variant"}
+        assert counters == counters_exp, (
+            f"{name}: span counters diverge from oracle\n"
+            f"real:   {counters}\noracle: {counters_exp}")
+        assert counters["pages_served"] > 0, f"{name}: nothing served"
 
-            reg = MetricsRegistry()
-            reg.observe_telemetry(telem, page_bytes=page_bytes)
-            snap = reg.snapshot()["counters"]
-            assert snap["bridge_pages_served_total"] == \
-                counters["pages_served"], name
-            assert snap['bridge_wire_pages_total{direction="cw"}'] == \
-                counters["wire_pages_cw"], name
-            assert snap['bridge_wire_pages_total{direction="ccw"}'] == \
-                counters["wire_pages_ccw"], name
-            assert snap["bridge_wire_bytes_total"] == \
-                counters["wire_bytes"], name
-            tenant_total = sum(
-                v for k, v in snap.items()
-                if k.startswith("bridge_tenant_pages_total"))
-            assert tenant_total == sum(counters["tenant_pages"].values())
-            print(f"ok: span/registry/oracle reconcile bit-exact [{name}]")
+        reg = MetricsRegistry()
+        reg.observe_telemetry(telem, page_bytes=page_bytes)
+        snap = reg.snapshot()["counters"]
+        assert snap["bridge_pages_served_total"] == \
+            counters["pages_served"], name
+        assert snap['bridge_wire_pages_total{direction="cw"}'] == \
+            counters["wire_pages_cw"], name
+        assert snap['bridge_wire_pages_total{direction="ccw"}'] == \
+            counters["wire_pages_ccw"], name
+        assert snap["bridge_wire_bytes_total"] == \
+            counters["wire_bytes"], name
+        tenant_total = sum(
+            v for k, v in snap.items()
+            if k.startswith("bridge_tenant_pages_total"))
+        assert tenant_total == sum(counters["tenant_pages"].values())
+        print(f"ok: span/registry/oracle reconcile bit-exact [{name}]")
     return rec
 
 
 def deterministic_trace_checks():
     """Two traced runs of the real datapath serialize byte-identically."""
-    mesh8 = jax.make_mesh((8,), ("data",))
+    mesh8 = make_mesh((8,), ("data",))
     rng = np.random.default_rng(17)
     pool = jnp.asarray(rng.normal(size=(N * PPN, PAGE)).astype(np.float32))
     table = MemPortTable.striped(48, N, PPN)
@@ -136,14 +136,13 @@ def deterministic_trace_checks():
     def traced_run() -> str:
         rec = TraceRecorder(ManualClock(start_us=10.0, tick_us=3.0),
                             process_name="obs-deterministic")
-        with bridge.use_mesh(mesh8):
-            pull = jax.jit(functools.partial(
-                bridge.pull_pages, mesh=mesh8, budget=3,
-                collect_telemetry=True))
-            with rec.span("transfer:deterministic", pages=6) as sp:
-                out, telem = pull(pool, want, table)
-                rec.fence((out, telem))
-            rec.annotate_telemetry(sp, telem, page_bytes=PAGE * 4)
+        pull = jax.jit(functools.partial(
+            bridge.pull_pages, mesh=mesh8, budget=3,
+            collect_telemetry=True))
+        with rec.span("transfer:deterministic", pages=6) as sp:
+            out, telem = pull(pool, want, table)
+            rec.fence((out, telem))
+        rec.annotate_telemetry(sp, telem, page_bytes=PAGE * 4)
         return rec.to_json(indent=1)
 
     a, b = traced_run(), traced_run()
@@ -155,22 +154,21 @@ def deterministic_trace_checks():
 def phase_attribution_checks():
     """Compiled-HLO phase op counts: unfused scales with depth, fused
     does not — the structural cause of the pipeline wall-clock regression."""
-    mesh8 = jax.make_mesh((8,), ("data",))
+    mesh8 = make_mesh((8,), ("data",))
     rng = np.random.default_rng(23)
     pool = jnp.asarray(rng.normal(size=(N * PPN, PAGE)).astype(np.float32))
     table = MemPortTable.striped(N * PPN, N, PPN)
     want = jnp.asarray(
         rng.integers(0, N * PPN, size=(N, 16)).astype(np.int32))
     counts = {}
-    with bridge.use_mesh(mesh8):
-        for fused in (False, True):
-            for c in (1, 4):
-                text = jax.jit(
-                    lambda p, w, t, _c=c, _f=fused: bridge.pull_pages(
-                        p, w, t, mesh=mesh8, budget=8, channels=_c,
-                        fused=_f)).lower(pool, want, table) \
-                    .compile().as_text()
-                counts[(fused, c)] = phase_op_counts(text)
+    for fused in (False, True):
+        for c in (1, 4):
+            text = jax.jit(
+                lambda p, w, t, _c=c, _f=fused: bridge.pull_pages(
+                    p, w, t, mesh=mesh8, budget=8, channels=_c,
+                    fused=_f)).lower(pool, want, table) \
+                .compile().as_text()
+            counts[(fused, c)] = phase_op_counts(text)
     for key, ops in counts.items():
         assert {"wire_req", "gather", "wire_data", "commit"} <= ops.keys(), (
             key, ops)
@@ -187,33 +185,32 @@ def phase_attribution_checks():
 def calibration_loop_checks():
     """Fit the perfmodel on real measured pulls; fitted must beat static,
     and the fitted chunk overhead must steer select_channels."""
-    mesh8 = jax.make_mesh((8,), ("data",))
+    mesh8 = make_mesh((8,), ("data",))
     rng = np.random.default_rng(29)
     pool = jnp.asarray(rng.normal(size=(N * PPN, 64)).astype(np.float32))
     table = MemPortTable.striped(N * PPN, N, PPN)
     bi = steering.bidirectional_program(N)
     page_bytes = 64 * 4
     samples = []
-    with bridge.use_mesh(mesh8):
-        for c in (1, 2, 4):
-            for cols in (8, 16):
-                want = jnp.asarray(rng.integers(
-                    0, N * PPN, size=(N, cols)).astype(np.int32))
-                pull = jax.jit(
-                    lambda p, w, t, _c=c: bridge.pull_pages(
-                        p, w, t, mesh=mesh8, budget=8, channels=_c,
-                        fused=False))
-                jax.block_until_ready(pull(pool, want, table))
-                reps = 3
-                t0 = time.perf_counter()
-                for _ in range(reps):
-                    r = pull(pool, want, table)
-                jax.block_until_ready(r)
-                us = (time.perf_counter() - t0) / reps * 1e6
-                rounds = steering.num_rounds(cols, 8)
-                feats = perfmodel.route_features(
-                    bi, page_bytes, 8, rounds=rounds, channels=c)
-                samples.append((feats, us))
+    for c in (1, 2, 4):
+        for cols in (8, 16):
+            want = jnp.asarray(rng.integers(
+                0, N * PPN, size=(N, cols)).astype(np.int32))
+            pull = jax.jit(
+                lambda p, w, t, _c=c: bridge.pull_pages(
+                    p, w, t, mesh=mesh8, budget=8, channels=_c,
+                    fused=False))
+            jax.block_until_ready(pull(pool, want, table))
+            reps = 3
+            t0 = time.perf_counter()
+            for _ in range(reps):
+                r = pull(pool, want, table)
+            jax.block_until_ready(r)
+            us = (time.perf_counter() - t0) / reps * 1e6
+            rounds = steering.num_rounds(cols, 8)
+            feats = perfmodel.route_features(
+                bi, page_bytes, 8, rounds=rounds, channels=c)
+            samples.append((feats, us))
 
     cal = perfmodel.Calibrator()
     for _ in range(4):

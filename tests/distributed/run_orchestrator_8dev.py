@@ -32,6 +32,7 @@ from repro.core.control_plane import ControlPlane  # noqa: E402
 from repro.core.memport import MemPortTable  # noqa: E402
 from repro.core.topology import Topology  # noqa: E402
 from repro.orchestrator import Orchestrator, TenantSpec  # noqa: E402
+from repro.launch.mesh import make_mesh  # noqa: E402
 
 TELEM_FIELDS = ("slot_served", "loopback_served", "spilled", "pruned",
                 "traffic", "epoch_cw", "epoch_ccw", "slot_intra",
@@ -49,7 +50,7 @@ def check_telem(name, got, exp):
 
 def tenant_oracle_checks():
     """Tenant lane bit-exact vs the oracle for all six program variants."""
-    mesh8 = jax.make_mesh((8,), ("data",))
+    mesh8 = make_mesh((8,), ("data",))
     n, ppn, page = 8, 8, 16
     rng = np.random.default_rng(41)
     pool = jnp.asarray(rng.normal(size=(n * ppn, page)).astype(np.float32))
@@ -73,52 +74,51 @@ def tenant_oracle_checks():
         ("hierarchical", hier),
         ("masked", steering.masked_ranks_program(hier, mask)),
     ]
-    with bridge.use_mesh(mesh8):
-        pull = jax.jit(functools.partial(
-            bridge.pull_pages, mesh=mesh8, budget=3, topology=topo,
-            collect_telemetry=True))
-        push = jax.jit(functools.partial(
-            bridge.push_pages, mesh=mesh8, budget=2, topology=topo,
-            collect_telemetry=True))
-        dest = np.stack([np.arange(4) + 6 * node for node in range(n)])
-        dlane = jnp.asarray((dest % 4).astype(np.int32))
-        payload = rng.normal(size=(n, 4, page)).astype(np.float32)
-        for name, prog in variants:
-            _, telem = pull(pool, want, table, program=prog,
-                            active_budget=ab, tenant_ids=lane)
-            exp = ref.expected_transfer_telemetry(
-                np.asarray(want), table, prog, num_nodes=n, budget=3,
-                active_budget=np.asarray(ab), topology=topo,
-                tenant_ids=np.asarray(lane))
-            check_telem(f"pull {name} tenants", telem, exp)
-            # reconciliation: tenant sums == untagged counters
-            np.testing.assert_array_equal(
-                np.asarray(telem.tenant_served).sum(-1),
-                np.asarray(telem.served_total()))
-            _, ptelem = push(pool, jnp.asarray(dest), jnp.asarray(payload),
-                             table, program=prog, tenant_ids=dlane)
-            check_telem(f"push {name} tenants", ptelem,
-                        ref.expected_transfer_telemetry(
-                            dest, table, prog, num_nodes=n, budget=2,
-                            topology=topo, tenant_ids=np.asarray(dlane)))
+    pull = jax.jit(functools.partial(
+        bridge.pull_pages, mesh=mesh8, budget=3, topology=topo,
+        collect_telemetry=True))
+    push = jax.jit(functools.partial(
+        bridge.push_pages, mesh=mesh8, budget=2, topology=topo,
+        collect_telemetry=True))
+    dest = np.stack([np.arange(4) + 6 * node for node in range(n)])
+    dlane = jnp.asarray((dest % 4).astype(np.int32))
+    payload = rng.normal(size=(n, 4, page)).astype(np.float32)
+    for name, prog in variants:
+        _, telem = pull(pool, want, table, program=prog,
+                        active_budget=ab, tenant_ids=lane)
+        exp = ref.expected_transfer_telemetry(
+            np.asarray(want), table, prog, num_nodes=n, budget=3,
+            active_budget=np.asarray(ab), topology=topo,
+            tenant_ids=np.asarray(lane))
+        check_telem(f"pull {name} tenants", telem, exp)
+        # reconciliation: tenant sums == untagged counters
+        np.testing.assert_array_equal(
+            np.asarray(telem.tenant_served).sum(-1),
+            np.asarray(telem.served_total()))
+        _, ptelem = push(pool, jnp.asarray(dest), jnp.asarray(payload),
+                         table, program=prog, tenant_ids=dlane)
+        check_telem(f"push {name} tenants", ptelem,
+                    ref.expected_transfer_telemetry(
+                        dest, table, prog, num_nodes=n, budget=2,
+                        topology=topo, tenant_ids=np.asarray(dlane)))
 
-        # acceptance: tenant share swaps never retrace.  New lanes, new
-        # windows (a different active budget) and new programs all hit the
-        # single compiled entry per callable.
-        for seed in (1, 2, 3):
-            r2 = np.random.default_rng(seed)
-            lane2 = jnp.asarray(r2.integers(0, 4, size=(n, 7)), jnp.int32)
-            ab2 = jnp.asarray(r2.integers(1, 4, size=(n,)), jnp.int32)
-            pull(pool, want, table, program=bi, active_budget=ab2,
-                 tenant_ids=lane2)
-        assert pull._cache_size() == 1, pull._cache_size()
-        assert push._cache_size() == 1, push._cache_size()
-        print("ok: tenant share swaps retrace-free (1 cache entry)")
+    # acceptance: tenant share swaps never retrace.  New lanes, new
+    # windows (a different active budget) and new programs all hit the
+    # single compiled entry per callable.
+    for seed in (1, 2, 3):
+        r2 = np.random.default_rng(seed)
+        lane2 = jnp.asarray(r2.integers(0, 4, size=(n, 7)), jnp.int32)
+        ab2 = jnp.asarray(r2.integers(1, 4, size=(n,)), jnp.int32)
+        pull(pool, want, table, program=bi, active_budget=ab2,
+             tenant_ids=lane2)
+    assert pull._cache_size() == 1, pull._cache_size()
+    assert push._cache_size() == 1, push._cache_size()
+    print("ok: tenant share swaps retrace-free (1 cache entry)")
 
 
 def orchestrator_e2e_checks():
     """Register -> lease -> compose -> measure -> re-fit on the real ring."""
-    mesh8 = jax.make_mesh((8,), ("data",))
+    mesh8 = make_mesh((8,), ("data",))
     topo = Topology.boards(2, 4)
     n, ppn, page = 8, 16, 8
     cp = ControlPlane(n, ppn, num_logical=n * ppn, topology=topo)
@@ -145,13 +145,12 @@ def orchestrator_e2e_checks():
     assert want.shape[0] == n
     pool = jnp.asarray(np.random.default_rng(0).normal(
         size=(n * ppn, page)).astype(np.float32))
-    with bridge.use_mesh(mesh8):
-        out, telem = bridge.pull_pages(
-            pool, jnp.asarray(want), orc.table(), mesh=mesh8,
-            budget=orc.budget, program=orc.route_program(),
-            active_budget=jnp.asarray(orc.active_budget()),
-            topology=topo, collect_telemetry=True,
-            tenant_ids=jnp.asarray(lane))
+    out, telem = bridge.pull_pages(
+        pool, jnp.asarray(want), orc.table(), mesh=mesh8,
+        budget=orc.budget, program=orc.route_program(),
+        active_budget=jnp.asarray(orc.active_budget()),
+        topology=topo, collect_telemetry=True,
+        tenant_ids=jnp.asarray(lane))
     exp = ref.expected_transfer_telemetry(
         want, orc.table(), orc.route_program(), num_nodes=n,
         budget=orc.budget, active_budget=orc.active_budget(),
@@ -185,7 +184,7 @@ def kv_append_pad_checks():
     (sequence 0's first pooled KV page) on every flush step.
     """
     from repro.core import kvbridge
-    mesh8 = jax.make_mesh((8,), ("data",))
+    mesh8 = make_mesh((8,), ("data",))
     b, kv, hd, pt, mp, n = 5, 2, 4, 4, 2, 8
     rng = np.random.default_rng(53)
     cache = kvbridge.init_cache(1, b, pt * mp, pt, kv, hd, mesh=mesh8,
@@ -197,10 +196,9 @@ def kv_append_pad_checks():
         tail_k=jnp.asarray(tails), tail_v=jnp.asarray(tails))
     lengths = jnp.full((b,), pt - 1, jnp.int32)   # every tail flushes
     k_new = jnp.asarray(rng.normal(size=(b, kv, hd)).astype(np.float32))
-    with bridge.use_mesh(mesh8):
-        out = kvbridge.append(layer, cache.table, lengths, k_new, k_new,
-                              page_tokens=pt, max_pages=mp, mesh=mesh8,
-                              mem_axis="data", budget=2)
+    out = kvbridge.append(layer, cache.table, lengths, k_new, k_new,
+                          page_tokens=pt, max_pages=mp, mesh=mesh8,
+                          mem_axis="data", budget=2)
     home = np.asarray(cache.table.home)
     slot = np.asarray(cache.table.slot)
     ppn_kv = out.k_pool.shape[0] // n

@@ -16,6 +16,7 @@ import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
 
 from repro.parallel import pipeline  # noqa: E402
+from repro.launch.mesh import make_mesh  # noqa: E402
 
 S, M, MB, D = 4, 6, 3, 16  # stages, microbatches, microbatch size, width
 
@@ -32,7 +33,7 @@ def sequential(params, x):
 
 def main():
     assert jax.device_count() == 8
-    mesh = jax.make_mesh((4, 2), ("stage", "data"))
+    mesh = make_mesh((4, 2), ("stage", "data"))
     rng = np.random.default_rng(0)
     params = {
         "w": jnp.asarray(rng.normal(size=(S, D, D)).astype(np.float32)) * 0.3,

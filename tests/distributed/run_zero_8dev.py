@@ -11,11 +11,12 @@ import numpy as np  # noqa: E402
 
 from repro.core import zero_bridge  # noqa: E402
 from repro.core.control_plane import ControlPlane  # noqa: E402
+from repro.launch.mesh import make_mesh  # noqa: E402
 
 
 def main():
     assert jax.device_count() == 8
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = make_mesh((4, 2), ("data", "model"))
     rng = np.random.default_rng(0)
     tree = {
         "w1": jnp.asarray(rng.normal(size=(40, 30)).astype(np.float32)),

@@ -16,6 +16,7 @@ from topologies import fake_telem, make_pool
 from repro.core import bridge, perfmodel, ref, steering
 from repro.core.memport import FREE, MemPortTable
 from repro.core.control_plane import ControlPlane
+from repro.launch.mesh import make_mesh
 
 make_pool_np = make_pool  # shared fixture (tests/topologies.py)
 
@@ -469,7 +470,19 @@ def test_migration_plan_roundtrips_through_table():
 # ---------------------------------------------------------------------------
 
 def _one_node_mesh():
-    return jax.make_mesh((1,), ("data",))
+    return make_mesh((1,), ("data",))
+
+
+def test_bridge_meshes_have_auto_axes():
+    """The mesh builder pins Auto axes; the bridge refuses Explicit ones
+    (jax.make_mesh's default) rather than mis-shard inside its maps."""
+    from jax.sharding import AxisType, PartitionSpec as P
+    assert _one_node_mesh().axis_types == (AxisType.Auto,)
+    explicit = jax.make_mesh((1,), ("data",),
+                             axis_types=(AxisType.Explicit,))
+    with pytest.raises(ValueError, match="Auto axes"):
+        bridge.shard_map(lambda x: x, explicit, in_specs=P("data"),
+                         out_specs=P("data"), mem_axis="data")
 
 
 def _run_pull_local(pool, want_row, active_budget, *, budget, rounds,
@@ -488,13 +501,12 @@ def _run_pull_local(pool, want_row, active_budget, *, budget, rounds,
     def mapped(pool_l, want_l, ab):
         return body(pool_l, want_l[0], table, ab[0], prog)[None]
 
-    with bridge.use_mesh(mesh):
-        return np.asarray(bridge.shard_map(
-            mapped, mesh,
-            in_specs=(P("data", None), P("data", None), P("data")),
-            out_specs=P("data", None, None), mem_axis="data",
-        )(pool, jnp.asarray(want_row)[None],
-          jnp.asarray([active_budget], jnp.int32))[0])
+    return np.asarray(bridge.shard_map(
+        mapped, mesh,
+        in_specs=(P("data", None), P("data", None), P("data")),
+        out_specs=P("data", None, None), mem_axis="data",
+    )(pool, jnp.asarray(want_row)[None],
+      jnp.asarray([active_budget], jnp.int32))[0])
 
 
 def _run_push_local(pool, dest_row, payload_rows, active_budget, *, budget,
@@ -510,15 +522,14 @@ def _run_push_local(pool, dest_row, payload_rows, active_budget, *, budget,
     def mapped(pool_l, dest_l, pay_l, ab):
         return body(pool_l, dest_l[0], pay_l[0], table, ab[0], prog)
 
-    with bridge.use_mesh(mesh):
-        return np.asarray(bridge.shard_map(
-            mapped, mesh,
-            in_specs=(P("data", None), P("data", None),
-                      P("data", None, None), P("data")),
-            out_specs=P("data", None), mem_axis="data",
-        )(pool, jnp.asarray(dest_row)[None],
-          jnp.asarray(payload_rows)[None],
-          jnp.asarray([active_budget], jnp.int32)))
+    return np.asarray(bridge.shard_map(
+        mapped, mesh,
+        in_specs=(P("data", None), P("data", None),
+                  P("data", None, None), P("data")),
+        out_specs=P("data", None), mem_axis="data",
+    )(pool, jnp.asarray(dest_row)[None],
+      jnp.asarray(payload_rows)[None],
+      jnp.asarray([active_budget], jnp.int32)))
 
 
 def test_pull_push_signature_parity():

@@ -9,6 +9,7 @@
   (local / bridge_pull / bridge_push) on a model with mixed SWA+full layers.
 """
 import dataclasses
+import pathlib
 import tempfile
 
 import jax
@@ -109,6 +110,28 @@ def test_serve_placements_agree(arch):
         outs[kv] = np.stack(seq)
     np.testing.assert_array_equal(outs["local"], outs["bridge_pull"])
     np.testing.assert_array_equal(outs["local"], outs["bridge_push"])
+
+
+@pytest.mark.parametrize("env_set", [False, True])
+def test_compile_cache_dir(monkeypatch, tmp_path, env_set):
+    """JAX_COMPILATION_CACHE_DIR wins untouched; otherwise the cache goes
+    to the fixed directory inside the checkout."""
+    from repro.launch import compile_cache
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        if env_set:
+            monkeypatch.setenv(compile_cache.ENV_VAR, str(tmp_path))
+            assert compile_cache.enable_compile_cache() == str(tmp_path)
+            assert jax.config.jax_compilation_cache_dir == before
+        else:
+            monkeypatch.delenv(compile_cache.ENV_VAR, raising=False)
+            got = compile_cache.enable_compile_cache()
+            assert got == str(compile_cache.REPO_CACHE_DIR)
+            assert compile_cache.REPO_CACHE_DIR.parent == \
+                pathlib.Path(__file__).resolve().parents[1]
+            assert jax.config.jax_compilation_cache_dir == got
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
 
 
 def test_long_context_skip_policy():
