@@ -422,40 +422,47 @@ class ControlPlane:
         traffic.  ``verify=False`` is the escape hatch for callers that
         *want* an unchecked install (benchmarked fault injection).
         """
-        compiled = program is None
-        if compiled:
-            program = self._compile_route_program(
-                requesters, bidirectional=bidirectional, prune=prune,
-                telemetry=telemetry)
-        if verify:
-            # Local import: keeps repro.core free of an import-time
-            # dependency on the analysis package.
-            from repro.analysis.findings import ProgramVerificationError
-            from repro.analysis.findings import errors as _errors
-            from repro.analysis.program_check import check_program
+        from repro.obs.trace import CP, maybe_span
+        rec = self.flight.trace if self.flight is not None else None
+        with maybe_span(rec, CP + "route_program"):
+            compiled = program is None
+            if compiled:
+                program = self._compile_route_program(
+                    requesters, bidirectional=bidirectional, prune=prune,
+                    telemetry=telemetry)
+            if verify:
+                # Local import: keeps repro.core free of an import-time
+                # dependency on the analysis package.
+                from repro.analysis.findings import ProgramVerificationError
+                from repro.analysis.findings import errors as _errors
+                from repro.analysis.program_check import check_program
 
-            bad = _errors(check_program(program, self.topology))
-            if bad:
-                raise ProgramVerificationError(bad)
-        if self.flight is not None:
-            from repro.obs import flight as _fl
+                with maybe_span(rec, CP + "verify"):
+                    bad = _errors(check_program(program, self.topology))
+                if bad:
+                    raise ProgramVerificationError(bad)
+            if self.flight is not None:
+                from repro.obs import flight as _fl
 
-            snap = (_fl.route_telemetry_snapshot(telemetry)
-                    if compiled else None)
-            measured = bool(snap is not None and snap["dist"]
-                            and sum(snap["dist"]) > 0)
-            self._journal(
-                "route_program", compiled=compiled,
-                requesters=(None if requesters is None
-                            else [int(r) for r in requesters]),
-                bidirectional=bidirectional, prune=prune, verified=verify,
-                variant=_fl.route_variant(
-                    compiled=compiled,
-                    hierarchical=self.topology.num_groups > 1,
-                    failed_link=self._failed_link_direction is not None,
-                    bidirectional=bidirectional, measured=measured),
-                telemetry=snap, program=_fl.program_to_dict(program),
-                digest=_fl.program_digest(program))
+                with maybe_span(rec, CP + "journal"):
+                    snap = (_fl.route_telemetry_snapshot(telemetry)
+                            if compiled else None)
+                    measured = bool(snap is not None and snap["dist"]
+                                    and sum(snap["dist"]) > 0)
+                    self._journal(
+                        "route_program", compiled=compiled,
+                        requesters=(None if requesters is None
+                                    else [int(r) for r in requesters]),
+                        bidirectional=bidirectional, prune=prune,
+                        verified=verify,
+                        variant=_fl.route_variant(
+                            compiled=compiled,
+                            hierarchical=self.topology.num_groups > 1,
+                            failed_link=(self._failed_link_direction
+                                         is not None),
+                            bidirectional=bidirectional, measured=measured),
+                        telemetry=snap, program=_fl.program_to_dict(program),
+                        digest=_fl.program_digest(program))
         return program
 
     def _compile_route_program(self, requesters: Optional[list[int]] = None,

@@ -4,10 +4,15 @@ Three layers, host-side only (nothing here runs under jit):
 
 - ``clock``: injectable monotonic clocks (wall for production, manual for
   deterministic tests).
-- ``trace``: ``TraceRecorder`` wraps jitted datapath calls in fenced
-  wall-clock spans (transaction -> round -> chunk -> phase), decorates
-  them with the matching ``BridgeTelemetry`` counters, and exports
-  Chrome-trace/Perfetto JSON.
+- ``trace``: ``TraceRecorder`` keeps a tree of host spans and exports
+  Chrome-trace/Perfetto JSON.  The serve loop records into it from
+  inside the program (``serve.control`` > ``orc.step`` > ``orc.refit`` >
+  ``cp.route_program``, ``engine.step`` > ``engine.dispatch`` /
+  ``engine.fetch``, ``req.queued``, ...) when its batcher, orchestrator
+  and engine are given ``recorder=``; ``recorder=None``, the default,
+  is the off switch.  ``profile=True`` mirrors each span into the
+  ``jax.profiler`` trace, on the device's clock.  Fenced spans
+  decorated with ``BridgeTelemetry`` counters time datapath calls.
 - ``metrics``: counter/gauge/log-bucketed-histogram registry with
   per-tenant / per-QoS / per-tier families fed by ``TelemetryAggregator``
   and spans, plus an SLO burn-rate monitor.

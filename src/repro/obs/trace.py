@@ -1,32 +1,63 @@
-"""Transaction tracing for the bridge datapath.
+"""Host-side spans: the serve loop, the control plane, datapath calls.
 
-A :class:`TraceRecorder` wraps *host-side* calls into jitted datapath
-functions in wall-clock spans.  Spans nest — the recorder keeps an open
-stack, so a transaction span contains its round spans, which contain
-channel-chunk and phase spans — and each span can be decorated with the
-``BridgeTelemetry`` counters of the work it fenced, making the trace a
-join of *when* (wall clock) and *what* (bit-exact page counts).
+A :class:`TraceRecorder` keeps a tree of wall-clock spans in memory.
+Spans nest (the recorder keeps an open stack), carry free-form args, and
+export as Chrome-trace JSON (``{"traceEvents": [...]}``, ``ph="X"``
+complete events) for https://ui.perfetto.dev or ``chrome://tracing``.
+The clock is injectable (:mod:`repro.obs.clock`): the serve loop passes
+its batcher's clock, so spans line up with the loop's own timestamps, and
+with a ``ManualClock`` a trace is reproducible byte for byte.
 
-Fencing matters under jax's async dispatch: a jitted call returns a
-future, so the recorder only closes a span after
-``jax.block_until_ready`` on the results (``fence=``).  The clock is
-injectable (:mod:`repro.obs.clock`); with a ``ManualClock`` the whole
-trace is deterministic and reproducible byte-for-byte.
+**The serve loop.**  ``ContinuousBatcher``, ``Orchestrator`` and
+``ModelDecodeEngine`` take ``recorder=``; the control plane records into
+the recorder of its flight journal (``FlightRecorder.trace``, which the
+orchestrator sets).  Names are ``<layer>.<phase>``, the layers being the
+prefixes below::
 
-Export is Chrome-trace JSON (``{"traceEvents": [...]}`` with ``ph="X"``
-complete events) — load it at https://ui.perfetto.dev or
-``chrome://tracing``.
+    serve.control                  one ContinuousBatcher.control tick,
+                                   args queue_depth, slots_active, ...
+      orc.step
+        orc.refit                  the control-period block
+          cp.route_program
+            cp.verify              the static program check
+            cp.journal             program_to_dict / program_digest
+      orc.refit_windows
+      serve.admit
+        orc.request_lease          one per admission attempt
+    serve.step_inputs
+    engine.step
+      engine.reset                 only when slots reset
+      engine.dispatch              the jitted serve step, issued
+      engine.fetch                 its tokens copied to the host
+    serve.observe
+      serve.retire                 one per retired sequence
+    req.queued                     arrival to admission, one per request
+    req<id>                        arrival to retirement
 
-For attributing time *inside* one jitted call (where no host clock can
-see), the datapath phases are annotated with ``jax.named_scope("obs:…")``
-so compiled-HLO metadata carries the phase name;
-:func:`phase_op_counts` tallies instructions per phase from HLO text.
+Journal records made inside a span carry its id (``span_id``).
+
+**Off is free.**  ``recorder=None`` (the default everywhere) turns the
+serve-loop spans off: each site then costs an attribute check and the
+shared no-op context :data:`NULL_SPAN` (:func:`maybe_span`).
+
+**The profiler's clock.**  ``TraceRecorder(profile=True)`` also opens a
+``jax.profiler.TraceAnnotation`` of the same name for every span, so
+while ``jax.profiler`` runs, each span lands on the trace's host plane,
+on the clock of the device's ``XLA Ops``/``XLA Modules`` lines.  A span
+recorded after the fact (:meth:`TraceRecorder.record_span`) can only mark
+the moment it was recorded there: its duration is in the recorder.
+
+Spans never fence by themselves: ``fence=`` blocks on a pytree of
+async-dispatched results before the span closes, for callers that time
+device work from the host (``benchmarks/bridge_latency.py``).
+:func:`phase_op_counts` attributes the work *inside* one jitted call by
+its ``jax.named_scope("obs:…")`` phases, from HLO text.
 """
 
 from __future__ import annotations
 
 import json
-from contextlib import contextmanager
+from contextlib import contextmanager, nullcontext
 from dataclasses import dataclass, field
 from typing import Any, Dict, Iterator, List, Optional
 
@@ -34,15 +65,35 @@ import numpy as np
 
 from repro.obs.clock import Clock, MonotonicClock
 
-#: Span categories used by the shipped instrumentation.  Free-form —
-#: these are conventions, not an enum the recorder enforces.
+#: Span categories.  Free-form: conventions, not an enum the recorder
+#: enforces.
 CAT_TRANSFER = "transfer"   # one pull/push transaction (all rounds)
 CAT_ROUND = "round"         # one bridge round
 CAT_CHUNK = "chunk"         # one channel chunk within a round
 CAT_PHASE = "phase"         # wire_req / gather / wire_data / commit
 CAT_COMPILE = "compile"     # trace/lower/compile of a jitted cell
-CAT_CONTROL = "control"     # orchestrator control period / refit
-CAT_REQUEST = "request"     # one serving request (queue -> retire)
+CAT_CONTROL = "control"     # serve.*, orc.*, cp.*: the host's serve tick
+CAT_STEP = "step"           # engine.*: one decode step, host side
+CAT_REQUEST = "request"     # req.*: one serving request's lifecycle
+
+#: The serve-loop span layers (module docstring); a span is named
+#: ``<prefix><phase>``.
+SERVE = "serve."            # ContinuousBatcher
+ORC = "orc."                # Orchestrator
+CP = "cp."                  # ControlPlane
+ENGINE = "engine."          # ModelDecodeEngine
+REQ = "req."                # one request
+PREFIXES = (SERVE, ORC, CP, ENGINE, REQ)
+
+#: What an instrumented site enters when its recorder is None.
+NULL_SPAN = nullcontext()
+
+
+def maybe_span(recorder: Optional["TraceRecorder"], name: str,
+               cat: str = CAT_CONTROL):
+    """``recorder.span(name, cat)``, or :data:`NULL_SPAN` (which yields
+    None) where ``recorder`` is None."""
+    return NULL_SPAN if recorder is None else recorder.span(name, cat)
 
 
 @dataclass
@@ -72,17 +123,28 @@ def _jsonable(v):
     return v
 
 
+def _args(attrs: Dict[str, Any]) -> Dict[str, Any]:
+    return {k: _jsonable(v) for k, v in attrs.items()} if attrs else {}
+
+
 class TraceRecorder:
-    """Collects a span tree and exports Chrome-trace/Perfetto JSON."""
+    """Collects a span tree and exports Chrome-trace/Perfetto JSON.
+
+    ``profile=True`` mirrors every span into the ``jax.profiler`` trace
+    as a ``TraceAnnotation`` of the same name (module docstring)."""
 
     def __init__(self, clock: Optional[Clock] = None, *, pid: int = 0,
-                 process_name: str = "repro-bridge"):
+                 process_name: str = "repro-bridge", profile: bool = False):
         self.clock = clock if clock is not None else MonotonicClock()
         self.pid = pid
         self.process_name = process_name
         self.spans: List[Span] = []
         self._stack: List[Span] = []
         self._next_id = 0
+        self._annotation = None
+        if profile:
+            from jax.profiler import TraceAnnotation
+            self._annotation = TraceAnnotation
 
     # ---------------------------------------------------------------- spans
     @contextmanager
@@ -90,10 +152,14 @@ class TraceRecorder:
              **attrs) -> Iterator[Span]:
         """Open a span around a block; ``fence=`` pytrees are blocked on
         before the span closes so async-dispatched device work is inside."""
+        ann = None
+        if self._annotation is not None:
+            ann = self._annotation(name)
+            ann.__enter__()
         s = Span(span_id=self._next_id,
                  parent_id=self._stack[-1].span_id if self._stack else None,
                  name=name, cat=cat, start_us=self.clock.now_us(),
-                 args={k: _jsonable(v) for k, v in attrs.items()})
+                 args=_args(attrs))
         self._next_id += 1
         self.spans.append(s)
         self._stack.append(s)
@@ -104,6 +170,8 @@ class TraceRecorder:
                 self.fence(fence)
             self._stack.pop()
             s.end_us = self.clock.now_us()
+            if ann is not None:
+                ann.__exit__(None, None, None)
 
     def record_span(self, name: str, cat: str = CAT_REQUEST, *,
                     start_us: float, end_us: float, **attrs) -> Span:
@@ -112,11 +180,16 @@ class TraceRecorder:
         For lifecycle spans whose start predates the call — e.g. a serving
         request recorded at retirement, whose arrival timestamp was taken
         steps ago — where the context-manager protocol cannot apply.  The
-        span is top-level (no parent inferred from the open stack).
+        span is top-level (no parent inferred from the open stack).  With
+        ``profile=True`` the profiler gets a zero-length event of the
+        name, at the time of the call.
         """
+        if self._annotation is not None:
+            with self._annotation(name):
+                pass
         s = Span(span_id=self._next_id, parent_id=None, name=name, cat=cat,
                  start_us=float(start_us), end_us=float(end_us),
-                 args={k: _jsonable(v) for k, v in attrs.items()})
+                 args=_args(attrs))
         self._next_id += 1
         self.spans.append(s)
         return s
@@ -129,7 +202,7 @@ class TraceRecorder:
         jax.block_until_ready(tree)
 
     def annotate(self, span: Span, **attrs) -> None:
-        span.args.update({k: _jsonable(v) for k, v in attrs.items()})
+        span.args.update(_args(attrs))
 
     def annotate_telemetry(self, span: Span, telem, *, page_bytes: int = 0,
                            tenant_names: Optional[Dict[int, str]] = None

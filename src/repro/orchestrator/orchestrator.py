@@ -43,6 +43,7 @@ from repro.core.control_plane import ControlPlane, MigrationStep
 from repro.obs.detect import Sentinel
 from repro.obs.flight import FlightRecorder
 from repro.obs.metrics import MetricsRegistry, SLOMonitor
+from repro.obs.trace import ORC, TraceRecorder, maybe_span
 from repro.orchestrator.admission import (ADMITTED, REJECTED,
                                           AdmissionController,
                                           AdmissionDecision, PendingRequest,
@@ -64,7 +65,8 @@ class Orchestrator:
                  queue_max_attempts: int = 0, queue_ttl_steps: int = 0,
                  migrate: bool = True, migration_limit: int = 8,
                  alpha: float = 0.25,
-                 flight: Optional[FlightRecorder] = None):
+                 flight: Optional[FlightRecorder] = None,
+                 recorder: Optional[TraceRecorder] = None):
         self.cp = control_plane
         self.budget = budget
         self.page_bytes = page_bytes
@@ -98,6 +100,11 @@ class Orchestrator:
         # initial route-program install is the journal's first decision);
         # the sentinel watches latency/residual/SLO/telemetry for drift.
         self.flight = flight if flight is not None else FlightRecorder()
+        # ``recorder`` takes the orchestrator's and the control plane's
+        # spans (``orc.*``, ``cp.*``): both record into the journal's
+        # trace, so each journal record names the span it was made in.
+        if recorder is not None:
+            self.flight.trace = recorder
         control_plane.attach_flight(self.flight)
         self.sentinel = Sentinel(registry=self.metrics, flight=self.flight,
                                  calibrator=self.calibrator, slo=self.slo)
@@ -267,35 +274,40 @@ class Orchestrator:
         lease grant with the serving request they decide, so
         ``FlightRecorder.why(request_id)`` can reconstruct the chain.
         """
-        if tenant_id not in self.specs:
-            raise KeyError(f"tenant {tenant_id} not registered")
-        spec = self.specs[tenant_id]
-        free_slots, free_logical = self._free_capacity()
-        total_slots, total_logical = self._total_capacity()
-        decision = self.admission.evaluate(
-            spec, num_pages, free_slots=free_slots,
-            free_logical=free_logical, held_pages=self.held_pages(tenant_id),
-            predicted_us=self.predicted_window_us(tenant_id),
-            total_slots=total_slots, total_logical=total_logical)
-        if decision.status == ADMITTED:
+        with maybe_span(self.flight.trace, ORC + "request_lease"):
+            if tenant_id not in self.specs:
+                raise KeyError(f"tenant {tenant_id} not registered")
+            spec = self.specs[tenant_id]
+            free_slots, free_logical = self._free_capacity()
+            total_slots, total_logical = self._total_capacity()
+            decision = self.admission.evaluate(
+                spec, num_pages, free_slots=free_slots,
+                free_logical=free_logical,
+                held_pages=self.held_pages(tenant_id),
+                predicted_us=self.predicted_window_us(tenant_id),
+                total_slots=total_slots, total_logical=total_logical)
+            if decision.status == ADMITTED:
+                self._rec_admission(decision, tenant_id, num_pages,
+                                    request_id)
+                lease = self._grant(spec, num_pages, policy, term,
+                                    auto_renew, request_id=request_id)
+                return decision, lease
+            if decision.status == QUEUED and queue:
+                self._rec_admission(decision, tenant_id, num_pages,
+                                    request_id)
+                return self.admission.enqueue(PendingRequest(
+                    tenant_id=tenant_id, num_pages=num_pages, policy=policy,
+                    term=term if term is not None else self.default_term,
+                    auto_renew=auto_renew,
+                    queued_step=self.step_count)), None
+            self.admission.rejected_total += 1
+            if decision.status == QUEUED:
+                # queue=False: a queueable request that was not parked is
+                # a rejection — a QUEUED status would promise a retry that
+                # will never happen.
+                decision = AdmissionDecision(REJECTED, decision.reason)
             self._rec_admission(decision, tenant_id, num_pages, request_id)
-            lease = self._grant(spec, num_pages, policy, term, auto_renew,
-                                request_id=request_id)
-            return decision, lease
-        if decision.status == QUEUED and queue:
-            self._rec_admission(decision, tenant_id, num_pages, request_id)
-            return self.admission.enqueue(PendingRequest(
-                tenant_id=tenant_id, num_pages=num_pages, policy=policy,
-                term=term if term is not None else self.default_term,
-                auto_renew=auto_renew, queued_step=self.step_count)), None
-        self.admission.rejected_total += 1
-        if decision.status == QUEUED:
-            # queue=False: a queueable request that was not parked is a
-            # rejection — a QUEUED status would promise a retry that will
-            # never happen.
-            decision = AdmissionDecision(REJECTED, decision.reason)
-        self._rec_admission(decision, tenant_id, num_pages, request_id)
-        return decision, None
+            return decision, None
 
     def _rec_admission(self, decision: AdmissionDecision, tenant_id: int,
                        num_pages: int,
@@ -361,110 +373,120 @@ class Orchestrator:
         Returns a report of the actions taken (expired/renewed lease ids,
         granted queued requests, new windows, migration plan).
         """
-        self.step_count += 1
-        if telemetry is not None:
-            self.telemetry.update(telemetry)
-            self.metrics.observe_telemetry(
-                telemetry, page_bytes=self.page_bytes, specs=self.specs)
-            self.metrics.observe_aggregator(self.telemetry)
-            self.flight.epoch = self.telemetry.steps
-            self.sentinel.check_telemetry(self.telemetry)
-        if measured_round_us is not None:
-            self.observe_round_latency(measured_round_us, rounds=rounds)
-        self.sentinel.check_slo()
+        with maybe_span(self.flight.trace, ORC + "step"):
+            self.step_count += 1
+            if telemetry is not None:
+                self.telemetry.update(telemetry)
+                self.metrics.observe_telemetry(
+                    telemetry, page_bytes=self.page_bytes, specs=self.specs)
+                self.metrics.observe_aggregator(self.telemetry)
+                self.flight.epoch = self.telemetry.steps
+                self.sentinel.check_telemetry(self.telemetry)
+            if measured_round_us is not None:
+                self.observe_round_latency(measured_round_us, rounds=rounds)
+            self.sentinel.check_slo()
 
-        expired, renewed = [], []
-        for lease in list(self.leases.values()):
-            if lease.expired(self.step_count):
-                if lease.auto_renew:
-                    lease.renew()
-                    renewed.append(lease.lease_id)
-                    self.flight.record("lease_renew",
-                                       lease_id=lease.lease_id,
-                                       tenant_id=lease.tenant_id,
-                                       expires_step=lease.expires_step)
-                else:
-                    self.flight.record("lease_expiry",
-                                       lease_id=lease.lease_id,
-                                       tenant_id=lease.tenant_id)
-                    self.release_lease(lease)
-                    expired.append(lease.lease_id)
+            expired, renewed = [], []
+            for lease in list(self.leases.values()):
+                if lease.expired(self.step_count):
+                    if lease.auto_renew:
+                        lease.renew()
+                        renewed.append(lease.lease_id)
+                        self.flight.record("lease_renew",
+                                           lease_id=lease.lease_id,
+                                           tenant_id=lease.tenant_id,
+                                           expires_step=lease.expires_step)
+                    else:
+                        self.flight.record("lease_expiry",
+                                           lease_id=lease.lease_id,
+                                           tenant_id=lease.tenant_id)
+                        self.release_lease(lease)
+                        expired.append(lease.lease_id)
 
-        # drain() removes every request whose retry is pointless (granted,
-        # now-rejected, deregistered tenant); only grants created a lease,
-        # so the report derives from the actual lease diff.
-        before = set(self.leases)
-        self.admission.drain(self._try_admit, step=self.step_count)
-        report: Dict[str, object] = {
-            "step": self.step_count, "expired": expired, "renewed": renewed,
-            "granted": [l.tenant_id for lid, l in self.leases.items()
-                        if lid not in before],
-            "evicted": [r.tenant_id for r in self.admission.last_evicted],
-            "refit": False, "migrations": [],
-        }
-        for r in self.admission.last_evicted:
-            self.flight.record("admission", tenant_id=r.tenant_id,
-                               num_pages=r.num_pages, status="EVICTED",
-                               reason="queue ttl/attempt limit")
-        if self.step_count % self.control_period == 0 and self.specs:
-            report["refit"] = True
-            if self.telemetry.steps > 0:
-                # A tenant whose last composed window was completely
-                # consumed may have more backlog hidden behind host-side
-                # clipping: let it bid as unbounded.  Consumed on read —
-                # a stale take from steps ago must not keep an idle tenant
-                # bidding as saturated forever.
-                saturated = [tid for tid, got in self._last_taken.items()
-                             if got >= self.schedule.windows.get(tid, 0) > 0]
-                self._last_taken = {}
-                self.schedule = self.scheduler.refit(
-                    list(self.specs.values()), self.telemetry,
-                    self.cp.num_nodes, saturated=saturated)
-                self.flight.record(
-                    "refit", mode="telemetry", budget=self.budget,
-                    num_nodes=self.cp.num_nodes,
-                    demand=np.asarray(self.telemetry.tenant_demand(),
-                                      float).tolist(),
-                    spilled=np.asarray(self.telemetry.last_tenant_spilled,
-                                       float).tolist(),
-                    saturated=list(saturated),
-                    windows=dict(self.schedule.windows))
-                if self._program_stale:
-                    # Placement changed this step: the measured compile
-                    # would prune the new (not-yet-measured) distances, so
-                    # placement reachability wins this period.
-                    self._program = self.cp.route_program()
-                    self._program_stale = False
-                else:
-                    self._program = self.cp.route_program(
-                        telemetry=self.telemetry)
-                if self.page_bytes > 0:
-                    self.channels = self.cp.select_channels(
-                        self.budget, self.page_bytes,
-                        telemetry=self.telemetry, program=self._program,
-                        calibrator=self.calibrator)
-                    self.metrics.gauge("bridge_selected_channels").set(
-                        self.channels)
-                if self.migrate:
-                    plan = self.cp.affinity_migration(
-                        self.telemetry, limit=self.migration_limit)
-                    self._migration_log.extend(plan)
-                    report["migrations"] = plan
-            else:
-                self.schedule = self.scheduler.compile(
-                    list(self.specs.values()))
-                self.flight.record("refit", mode="compile",
-                                   budget=self.budget,
-                                   windows=dict(self.schedule.windows))
+            # drain() removes every request whose retry is pointless
+            # (granted, now-rejected, deregistered tenant); only grants
+            # created a lease, so the report derives from the actual lease
+            # diff.
+            before = set(self.leases)
+            self.admission.drain(self._try_admit, step=self.step_count)
+            report: Dict[str, object] = {
+                "step": self.step_count, "expired": expired,
+                "renewed": renewed,
+                "granted": [l.tenant_id for lid, l in self.leases.items()
+                            if lid not in before],
+                "evicted": [r.tenant_id for r in self.admission.last_evicted],
+                "refit": False, "migrations": [],
+            }
+            for r in self.admission.last_evicted:
+                self.flight.record("admission", tenant_id=r.tenant_id,
+                                   num_pages=r.num_pages, status="EVICTED",
+                                   reason="queue ttl/attempt limit")
+            if self.step_count % self.control_period == 0 and self.specs:
+                report["refit"] = True
+                with maybe_span(self.flight.trace, ORC + "refit"):
+                    self._refit(report)
+            self.flight.record(
+                "step_report", step=self.step_count, expired=expired,
+                renewed=renewed, granted=report["granted"],
+                evicted=report["evicted"], refit=report["refit"],
+                migrations=len(report["migrations"]))
+            return report
+
+    def _refit(self, report: Dict[str, object]) -> None:
+        """The control period's half of :meth:`step`: re-fit the QoS
+        schedule and refresh the route program (and, with a page size,
+        the pipeline depth; with ``migrate``, the migration plan)."""
+        if self.telemetry.steps > 0:
+            # A tenant whose last composed window was completely
+            # consumed may have more backlog hidden behind host-side
+            # clipping: let it bid as unbounded.  Consumed on read —
+            # a stale take from steps ago must not keep an idle tenant
+            # bidding as saturated forever.
+            saturated = [tid for tid, got in self._last_taken.items()
+                         if got >= self.schedule.windows.get(tid, 0) > 0]
+            self._last_taken = {}
+            self.schedule = self.scheduler.refit(
+                list(self.specs.values()), self.telemetry,
+                self.cp.num_nodes, saturated=saturated)
+            self.flight.record(
+                "refit", mode="telemetry", budget=self.budget,
+                num_nodes=self.cp.num_nodes,
+                demand=np.asarray(self.telemetry.tenant_demand(),
+                                  float).tolist(),
+                spilled=np.asarray(self.telemetry.last_tenant_spilled,
+                                   float).tolist(),
+                saturated=list(saturated),
+                windows=dict(self.schedule.windows))
+            if self._program_stale:
+                # Placement changed this step: the measured compile
+                # would prune the new (not-yet-measured) distances, so
+                # placement reachability wins this period.
                 self._program = self.cp.route_program()
                 self._program_stale = False
-            report["windows"] = dict(self.schedule.windows)
-        self.flight.record(
-            "step_report", step=self.step_count, expired=expired,
-            renewed=renewed, granted=report["granted"],
-            evicted=report["evicted"], refit=report["refit"],
-            migrations=len(report["migrations"]))
-        return report
+            else:
+                self._program = self.cp.route_program(
+                    telemetry=self.telemetry)
+            if self.page_bytes > 0:
+                self.channels = self.cp.select_channels(
+                    self.budget, self.page_bytes,
+                    telemetry=self.telemetry, program=self._program,
+                    calibrator=self.calibrator)
+                self.metrics.gauge("bridge_selected_channels").set(
+                    self.channels)
+            if self.migrate:
+                plan = self.cp.affinity_migration(
+                    self.telemetry, limit=self.migration_limit)
+                self._migration_log.extend(plan)
+                report["migrations"] = plan
+        else:
+            self.schedule = self.scheduler.compile(
+                list(self.specs.values()))
+            self.flight.record("refit", mode="compile",
+                               budget=self.budget,
+                               windows=dict(self.schedule.windows))
+            self._program = self.cp.route_program()
+            self._program_stale = False
+        report["windows"] = dict(self.schedule.windows)
 
     def refit_windows(self, demand: Dict[int, float]) -> Schedule:
         """Re-fit the QoS schedule from serving-layer queue depths.
@@ -476,13 +498,14 @@ class Orchestrator:
         hands its live per-tenant queue depths here as the demand signal,
         so the bridge windows track offered load a control period early.
         """
-        demand = {tid: max(float(d), 0.0) for tid, d in demand.items()}
-        self.schedule = self.scheduler.compile(
-            list(self.specs.values()), demand)
-        self.flight.record("refit", mode="windows", budget=self.budget,
-                           demand={str(k): v for k, v in demand.items()},
-                           windows=dict(self.schedule.windows))
-        return self.schedule
+        with maybe_span(self.flight.trace, ORC + "refit_windows"):
+            demand = {tid: max(float(d), 0.0) for tid, d in demand.items()}
+            self.schedule = self.scheduler.compile(
+                list(self.specs.values()), demand)
+            self.flight.record("refit", mode="windows", budget=self.budget,
+                               demand={str(k): v for k, v in demand.items()},
+                               windows=dict(self.schedule.windows))
+            return self.schedule
 
     def _try_admit(self, req: PendingRequest) -> bool:
         """Queue-drain executor: True removes the request from the queue.

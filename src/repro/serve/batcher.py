@@ -51,7 +51,8 @@ import numpy as np
 
 from repro.obs.clock import Clock, ManualClock
 from repro.obs.metrics import MetricsRegistry
-from repro.obs.trace import CAT_REQUEST, TraceRecorder
+from repro.obs.trace import (CAT_REQUEST, CAT_STEP, ENGINE, REQ, SERVE,
+                             TraceRecorder, maybe_span)
 from repro.orchestrator.orchestrator import Orchestrator
 from repro.orchestrator.scheduler import WeightedFairScheduler
 from repro.serve.traffic import Request, TrafficGenerator
@@ -128,7 +129,8 @@ class ModelDecodeEngine:
     """
 
     def __init__(self, run, params, *, batch: int, max_len: int,
-                 mesh=None, page_tokens: int = 512, dtype=None):
+                 mesh=None, page_tokens: int = 512, dtype=None,
+                 recorder: Optional[TraceRecorder] = None):
         import jax
         import jax.numpy as jnp
 
@@ -146,6 +148,7 @@ class ModelDecodeEngine:
         self._step = jax.jit(build_serve_step(run, self.cache_ops),
                              donate_argnums=(1,))
         self._jnp = jnp
+        self.recorder = recorder
 
     def lower(self):
         """The decode step lowered for this engine's params and state
@@ -155,12 +158,21 @@ class ModelDecodeEngine:
 
     def step(self, tokens: np.ndarray,
              reset: Sequence[int] = ()) -> np.ndarray:
-        if len(reset):
-            idx = np.asarray(list(reset), np.int32)
-            self.state["lengths"] = self.state["lengths"].at[idx].set(0)
-        out, self.state = self._step(self.params, self.state,
-                                     self._jnp.asarray(tokens))
-        return np.asarray(out)
+        """One decode step; spans ``engine.step`` > ``engine.reset`` (slot
+        resets), ``engine.dispatch`` (the serve step issued, async) and
+        ``engine.fetch`` (waiting for its tokens on the host)."""
+        rec = self.recorder
+        with maybe_span(rec, ENGINE + "step", CAT_STEP):
+            if len(reset):
+                with maybe_span(rec, ENGINE + "reset", CAT_STEP):
+                    idx = np.asarray(list(reset), np.int32)
+                    self.state["lengths"] = \
+                        self.state["lengths"].at[idx].set(0)
+            with maybe_span(rec, ENGINE + "dispatch", CAT_STEP):
+                out, self.state = self._step(self.params, self.state,
+                                             self._jnp.asarray(tokens))
+            with maybe_span(rec, ENGINE + "fetch", CAT_STEP):
+                return np.asarray(out)
 
 
 SHED_TERMINAL = "terminal"     # can never fit: quota / whole-pool capacity
@@ -181,8 +193,13 @@ class ContinuousBatcher:
     depths each control period, and admits queued requests into free
     slots — taking one KV-page lease per sequence.  ``observe()``
     retires finished sequences: lease released, slot freed, per-QoS
-    latency/TTFT histograms recorded (and a ``CAT_REQUEST`` trace span,
-    when a recorder is attached).
+    latency/TTFT histograms recorded.
+
+    With a ``recorder`` each call is a span (``serve.control``,
+    ``serve.step_inputs``, ``serve.observe``; :mod:`repro.obs.trace`
+    lists the tree), each admission records ``req.queued`` and each
+    retirement ``req<id>``; give the orchestrator and the engine the same
+    recorder for their spans.
     """
 
     def __init__(self, orc: Orchestrator, *, num_slots: int,
@@ -278,18 +295,22 @@ class ContinuousBatcher:
         request windows from the *serving* queue depths, then admits
         queued requests into free decode slots under the slot policy.
         """
-        self.step_count += 1
-        self.orc.step(telemetry=telemetry,
-                      measured_round_us=measured_round_us)
-        if self.orc.specs and \
-                self.orc.step_count % self.orc.control_period == 0:
-            self.orc.refit_windows(self._slot_demand())
-        admitted = self._admit()
-        self.peak_in_flight = max(self.peak_in_flight, self.in_flight())
-        g = self.registry.gauge
-        g("serve_slots_active").set(self.active_count())
-        g("serve_queue_depth").set(self.queue_depth())
-        g("serve_in_flight").set(self.in_flight())
+        rec = self.recorder
+        with maybe_span(rec, SERVE + "control") as sp:
+            self.step_count += 1
+            self.orc.step(telemetry=telemetry,
+                          measured_round_us=measured_round_us)
+            if self.orc.specs and \
+                    self.orc.step_count % self.orc.control_period == 0:
+                self.orc.refit_windows(self._slot_demand())
+            with maybe_span(rec, SERVE + "admit"):
+                admitted = self._admit()
+            in_flight = self.in_flight()
+            self.peak_in_flight = max(self.peak_in_flight, in_flight)
+            if sp is not None:
+                rec.annotate(sp, queue_depth=self.queue_depth(),
+                             slots_active=self.active_count(),
+                             in_flight=in_flight, admitted=len(admitted))
         return admitted
 
     def _slot_demand(self) -> Dict[int, float]:
@@ -375,6 +396,11 @@ class ContinuousBatcher:
         self.slots[slot] = seq
         self._pending_reset.append(slot)
         admitted.append(seq)
+        if self.recorder is not None:
+            self.recorder.record_span(
+                REQ + "queued", CAT_REQUEST, start_us=seq.arrive_us,
+                end_us=seq.admit_us, req_id=req.req_id,
+                tenant=req.tenant_id)
         return True
 
     # -- the decode-step halves ------------------------------------------------
@@ -385,34 +411,37 @@ class ContinuousBatcher:
         must zero their ``lengths`` before consuming these tokens.  Free
         slots feed token 0; their output is discarded.
         """
-        tokens = np.zeros((self.num_slots,), np.int32)
-        for seq in self.slots:
-            if seq is not None:
-                tokens[seq.slot] = seq.next_feed()
-                seq.started = True
-        resets, self._pending_reset = self._pending_reset, []
-        return tokens, resets
+        with maybe_span(self.recorder, SERVE + "step_inputs"):
+            tokens = np.zeros((self.num_slots,), np.int32)
+            for seq in self.slots:
+                if seq is not None:
+                    tokens[seq.slot] = seq.next_feed()
+                    seq.started = True
+            resets, self._pending_reset = self._pending_reset, []
+            return tokens, resets
 
     def observe(self, next_tokens: np.ndarray) -> List[SeqState]:
         """Fold one engine step's emissions; returns retired sequences."""
-        out = np.asarray(next_tokens)
-        finished: List[SeqState] = []
-        for seq in self.slots:
-            if seq is None or not seq.started:
-                continue
-            fed_idx = seq.fed
-            seq.fed += 1
-            if fed_idx >= seq.req.prompt_len - 1:
-                # Feeding the last prompt token (or any later feed) emits
-                # a generated token.
-                seq.out.append(int(out[seq.slot]))
-                if seq.first_token_us is None:
-                    seq.first_token_us = self.clock.now_us()
-            if seq.done:
-                finished.append(seq)
-        for seq in finished:
-            self._retire(seq)
-        return finished
+        with maybe_span(self.recorder, SERVE + "observe"):
+            out = np.asarray(next_tokens)
+            finished: List[SeqState] = []
+            for seq in self.slots:
+                if seq is None or not seq.started:
+                    continue
+                fed_idx = seq.fed
+                seq.fed += 1
+                if fed_idx >= seq.req.prompt_len - 1:
+                    # Feeding the last prompt token (or any later feed)
+                    # emits a generated token.
+                    seq.out.append(int(out[seq.slot]))
+                    if seq.first_token_us is None:
+                        seq.first_token_us = self.clock.now_us()
+                if seq.done:
+                    finished.append(seq)
+            for seq in finished:
+                with maybe_span(self.recorder, SERVE + "retire"):
+                    self._retire(seq)
+            return finished
 
     def _retire(self, seq: SeqState) -> None:
         lease = self.orc.leases.get(seq.lease_id)
@@ -449,33 +478,39 @@ class ContinuousBatcher:
     def why(self, request_id: int) -> Dict[str, object]:
         """Causal chain behind one request: admission verdicts, lease
         grant/release, the route program it ran under (from the flight
-        journal) plus its ``req{id}`` span and the bridge-round spans that
-        overlap its in-flight window (from the trace recorder)."""
+        journal); with a recorder, its ``req.queued`` and ``req<id>``
+        spans and the ``serve.control`` and ``engine.step`` spans that
+        overlap its time from arrival to retirement (to now, while it is
+        in flight)."""
         out: Dict[str, object] = {
             "request_id": int(request_id),
             "decisions": [r.to_json() for r in
                           self.orc.flight.why(request_id)],
             "spans": [],
         }
-        if self.recorder is not None:
-            req_span = None
-            for s in self.recorder.spans:
-                if s.name == f"req{request_id}":
-                    req_span = s
-                    break
-            if req_span is not None:
-                lo, hi = req_span.start_us, (req_span.end_us
-                                             if req_span.end_us is not None
-                                             else float("inf"))
-                for s in self.recorder.spans:
-                    if s is req_span or (
-                            s.end_us is not None and s.end_us >= lo
-                            and s.start_us <= hi
-                            and s.cat in ("round", "control", CAT_REQUEST)):
-                        out["spans"].append({
-                            "name": s.name, "cat": s.cat,
-                            "start_us": s.start_us, "end_us": s.end_us,
-                            "args": dict(s.args)})
+        rec = self.recorder
+        if rec is None:
+            return out
+        done = f"req{request_id}"
+        own = [s for s in rec.spans if s.name == done or (
+            s.name == REQ + "queued" and s.args.get("req_id") == request_id)]
+        if not own:
+            return out
+        lo = min(s.start_us for s in own)
+        hi = (max(s.end_us for s in own) if any(s.name == done for s in own)
+              else self.clock.now_us())
+        ids = {s.span_id for s in own}
+        ticks = (SERVE + "control", ENGINE + "step")
+
+        def overlaps(s) -> bool:
+            return (s.name in ticks and s.end_us is not None
+                    and s.end_us >= lo and s.start_us <= hi)
+
+        out["spans"] = [{"name": s.name, "cat": s.cat,
+                         "start_us": s.start_us, "end_us": s.end_us,
+                         "args": dict(s.args)}
+                        for s in rec.spans
+                        if s.span_id in ids or overlaps(s)]
         return out
 
     def describe(self) -> str:

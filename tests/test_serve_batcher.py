@@ -24,7 +24,7 @@ import pytest
 from repro.core.control_plane import ControlPlane
 from repro.obs.clock import ManualClock
 from repro.obs.metrics import MetricsRegistry
-from repro.obs.trace import CAT_REQUEST, TraceRecorder
+from repro.obs.trace import CAT_REQUEST, REQ, TraceRecorder
 from repro.orchestrator import Orchestrator, TenantSpec
 from repro.serve.batcher import (ContinuousBatcher, SimulatedDecodeEngine,
                                  serve_loop, solo_reference)
@@ -368,8 +368,12 @@ def test_latency_histograms_and_request_spans():
         assert q["count"] > 0
         assert 0 < q["p50"] <= q["p99"]
     assert res["latency_us"].keys() == lat.keys()
-    # one CAT_REQUEST span per retirement, wall-clock consistent
-    spans = recorder.find_all(cat=CAT_REQUEST)
+    # one req<id> span per retirement and one req.queued per admission
+    # (CAT_REQUEST both), wall-clock consistent
+    queued = recorder.find_all(name=REQ + "queued")
+    assert len(queued) == res["completed"]
+    spans = [s for s in recorder.find_all(cat=CAT_REQUEST)
+             if s.name != REQ + "queued"]
     assert len(spans) == res["completed"]
     for s in spans:
         # a 1-prompt/1-output request can legally retire in its arrival
