@@ -41,6 +41,7 @@ recompiles anything.
 from __future__ import annotations
 
 import dataclasses
+from collections import OrderedDict
 from dataclasses import dataclass, field
 from typing import Literal, Optional
 
@@ -79,6 +80,16 @@ class NodeState:
     step_times: list = field(default_factory=list)
 
 
+@dataclass
+class RouteCounts:
+    """What :meth:`ControlPlane.route_program` did, call by call."""
+
+    compiled: int = 0        # programs compiled from placement / telemetry
+    installed: int = 0       # new contents put on the device by a compile
+    verified: int = 0        # static verifications run
+    verify_skipped: int = 0  # content already verified clean: not again
+
+
 class ControlPlane:
     """Owns placement for one pool (num_nodes x pages_per_node slots)."""
 
@@ -104,6 +115,10 @@ class ControlPlane:
         self._next_region = 0
         self.nodes = [NodeState() for _ in range(num_nodes)]
         self._failed_link_direction: Optional[int] = None
+        self.route_counts = RouteCounts()
+        # Content keys of programs verified clean against _verified_for.
+        self._verified: OrderedDict = OrderedDict()
+        self._verified_for = self.topology
         # Optional flight recorder (repro.obs.flight.FlightRecorder);
         # duck-typed so repro.core keeps no import-time obs dependency.
         self.flight = None
@@ -421,26 +436,56 @@ class ControlPlane:
         datapath a schedule that would drop, double-serve or collide
         traffic.  ``verify=False`` is the escape hatch for callers that
         *want* an unchecked install (benchmarked fault injection).
+
+        Verification is a pure function of the program and the topology,
+        so a content already verified clean against this plane's topology
+        is not verified again (only programs that passed are remembered).
+        The ``cp.route_program`` span says ``reused=True`` when the call
+        put no new program on the device and verified nothing;
+        :attr:`route_counts` keeps the totals.
         """
         from repro.obs.trace import CP, maybe_span
         rec = self.flight.trace if self.flight is not None else None
-        with maybe_span(rec, CP + "route_program"):
+        counts = self.route_counts
+        with maybe_span(rec, CP + "route_program") as span:
             compiled = program is None
+            puts = steering.device_puts()
             if compiled:
                 program = self._compile_route_program(
                     requesters, bidirectional=bidirectional, prune=prune,
                     telemetry=telemetry)
+                counts.compiled += 1
+            installed = steering.device_puts() > puts
+            counts.installed += installed
+            host = program.on_host()
+            checked = False
             if verify:
-                # Local import: keeps repro.core free of an import-time
-                # dependency on the analysis package.
-                from repro.analysis.findings import ProgramVerificationError
-                from repro.analysis.findings import errors as _errors
-                from repro.analysis.program_check import check_program
+                if self._verified_for is not self.topology:
+                    self._verified.clear()
+                    self._verified_for = self.topology
+                key = steering.content_key(host)
+                if key in self._verified:
+                    self._verified.move_to_end(key)
+                    counts.verify_skipped += 1
+                else:
+                    # Local import: keeps repro.core free of an import-time
+                    # dependency on the analysis package.
+                    from repro.analysis.findings import \
+                        ProgramVerificationError
+                    from repro.analysis.findings import errors as _errors
+                    from repro.analysis.program_check import check_program
 
-                with maybe_span(rec, CP + "verify"):
-                    bad = _errors(check_program(program, self.topology))
-                if bad:
-                    raise ProgramVerificationError(bad)
+                    with maybe_span(rec, CP + "verify"):
+                        bad = _errors(check_program(host, self.topology))
+                    counts.verified += 1
+                    checked = True
+                    if bad:
+                        raise ProgramVerificationError(bad)
+                    self._verified[key] = None
+                    if len(self._verified) > steering.PROGRAM_CACHE_SIZE:
+                        self._verified.popitem(last=False)
+            if span is not None:
+                rec.annotate(span, reused=not (installed or checked))
             if self.flight is not None:
                 from repro.obs import flight as _fl
 
@@ -461,8 +506,8 @@ class ControlPlane:
                             failed_link=(self._failed_link_direction
                                          is not None),
                             bidirectional=bidirectional, measured=measured),
-                        telemetry=snap, program=_fl.program_to_dict(program),
-                        digest=_fl.program_digest(program))
+                        telemetry=snap, program=_fl.program_to_dict(host),
+                        digest=_fl.program_digest(host))
         return program
 
     def _compile_route_program(self, requesters: Optional[list[int]] = None,
@@ -737,11 +782,9 @@ class ControlPlane:
 
     # -- introspection ----------------------------------------------------------
     def occupancy(self) -> np.ndarray:
-        occ = np.zeros((self.num_nodes,), np.int64)
-        for h in self._home:
-            if h != FREE:
-                occ[h] += 1
-        return occ
+        """Pages homed on each node."""
+        return np.bincount(self._home[self._home != FREE],
+                           minlength=self.num_nodes)
 
     def describe(self) -> str:
         occ = self.occupancy()

@@ -14,6 +14,13 @@ pruned, link-avoiding, or **hierarchical** for a board + rack fabric
 (:func:`hierarchical_program`) — without ever recompiling the jitted
 datapath.
 
+Programs are compiled on the host: every builder does its arithmetic on
+numpy arrays, and one that starts from a base program reads the base's host
+copy (:meth:`RouteProgram.on_host`), never the device.  The device program
+comes from one content-keyed constructor (:func:`make_program`) that keeps
+the last :data:`PROGRAM_CACHE_SIZE` contents: a program equal to one already
+on the device is that program, with no device put.
+
 Key identity the programs exploit: on an N-ring the permutation
 ``rank -> rank + d (mod N)`` is *the same permutation* as
 ``rank -> rank - (N - d) (mod N)``.  Slot ``k`` of the datapath (serving
@@ -27,6 +34,7 @@ program covers all N-1 distances in ⌊N/2⌋ epochs instead of N-1.
 """
 from __future__ import annotations
 
+from collections import OrderedDict
 from dataclasses import dataclass
 from typing import Optional
 
@@ -113,36 +121,50 @@ class RouteProgram:
         return self.num_slots + 1
 
     # -- host-side accounting (benchmarks / perfmodel / tests) ---------------
+    def on_host(self) -> "RouteProgram":
+        """This program with read-only numpy fields in the device dtypes.
+
+        A program :func:`make_program` put on the device answers with the
+        host copy it was built from; any other reads each field once.
+        """
+        if all(isinstance(getattr(self, f), np.ndarray)
+               and getattr(self, f).dtype == dt for f, dt in PROGRAM_FIELDS):
+            return self
+        host = _INSTALLED.host_of(self)
+        return host if host is not None else _host_program(
+            self.offsets, self.epoch, self.live, self.rank_epoch)
+
     def num_epochs(self) -> int:
         """Circuit epochs the program occupies (max served epoch + 1)."""
         served = self.rank_served()
-        re = np.asarray(self.rank_epoch)
+        re = self.on_host().rank_epoch
         return int(re[served].max()) + 1 if served.any() else 0
 
     def live_distances(self) -> np.ndarray:
         """Ring distances with a wired circuit (sorted)."""
-        return np.nonzero(np.asarray(self.live))[0] + 1
+        return np.nonzero(self.on_host().live)[0] + 1
 
     def hops(self) -> np.ndarray:
         """Flat-ring hop count per slot (0 on dead slots)."""
-        return np.abs(np.asarray(self.offsets))
+        return np.abs(self.on_host().offsets)
 
     def rank_served(self) -> np.ndarray:
         """bool[N-1, N]: does slot k carry requester rank r's traffic."""
-        return (np.asarray(self.live)[:, None]
-                & (np.asarray(self.rank_epoch) >= 0))
+        h = self.on_host()
+        return h.live[:, None] & (h.rank_epoch >= 0)
 
     def validate(self) -> None:
         """Raise on incongruent offsets or an inconsistent group mask."""
         n = self.num_nodes
-        off, lv = np.asarray(self.offsets), np.asarray(self.live)
+        h = self.on_host()
+        off, lv = h.offsets, h.live
         d = np.arange(1, n)
         bad = lv & ((off % n) != d)
         if bad.any():
             raise ValueError(
                 f"slots {np.nonzero(bad)[0].tolist()} drive offsets "
                 f"{off[bad].tolist()} incongruent with their distances")
-        re = np.asarray(self.rank_epoch)
+        re = h.rank_epoch
         if re.shape != (n - 1, n):
             raise ValueError(f"rank_epoch has shape {re.shape}; expected "
                              f"{(n - 1, n)}")
@@ -163,15 +185,96 @@ def _rank_epoch_from(epoch: np.ndarray, live: np.ndarray) -> np.ndarray:
     return np.repeat(col[:, None], n, axis=1)
 
 
-def _program(off: np.ndarray, epoch: np.ndarray, live: np.ndarray,
-             rank_epoch: Optional[np.ndarray] = None) -> RouteProgram:
+#: A program's fields and their dtypes on the device (and on the host).
+PROGRAM_FIELDS = (("offsets", np.int32), ("epoch", np.int32),
+                  ("live", np.bool_), ("rank_epoch", np.int32))
+
+#: Distinct program contents kept on the device by :func:`make_program`.
+PROGRAM_CACHE_SIZE = 8
+
+
+def _host_program(*arrays) -> RouteProgram:
+    """A RouteProgram of read-only numpy copies in the device dtypes."""
+    fields = {}
+    for (name, dtype), a in zip(PROGRAM_FIELDS, arrays):
+        a = np.array(a, dtype)
+        a.flags.writeable = False
+        fields[name] = a
+    return RouteProgram(**fields)
+
+
+def content_key(program: RouteProgram) -> tuple:
+    """Each field's shape and bytes in its device dtype: the fingerprint
+    :func:`repro.obs.flight.program_digest` hashes.  Equal keys, equal
+    programs."""
+    h = program.on_host()
+    return tuple((getattr(h, f).shape, getattr(h, f).tobytes())
+                 for f, _ in PROGRAM_FIELDS)
+
+
+class _DevicePrograms:
+    """The last few route programs put on the device, keyed by content."""
+
+    def __init__(self, capacity: int):
+        self.capacity = capacity
+        self.puts = 0
+        self._by_key: OrderedDict = OrderedDict()  # key -> (device, host)
+
+    def get(self, host: RouteProgram) -> RouteProgram:
+        key = content_key(host)
+        hit = self._by_key.get(key)
+        if hit is not None:
+            self._by_key.move_to_end(key)
+            return hit[0]
+        dev = RouteProgram(**{f: jnp.asarray(getattr(host, f))
+                              for f, _ in PROGRAM_FIELDS})
+        self.puts += 1
+        if any(isinstance(getattr(dev, f), jax.core.Tracer)
+               for f, _ in PROGRAM_FIELDS):
+            return dev  # built under a trace: nothing to keep
+        self._by_key[key] = (dev, host)
+        if len(self._by_key) > self.capacity:
+            self._by_key.popitem(last=False)
+        return dev
+
+    def host_of(self, program: RouteProgram) -> Optional[RouteProgram]:
+        for dev, host in self._by_key.values():
+            if dev is program:
+                return host
+        return None
+
+
+# One per process, as the builders are free functions; what it holds is
+# immutable (frozen programs, read-only host copies), so callers share it.
+_INSTALLED = _DevicePrograms(PROGRAM_CACHE_SIZE)
+
+
+def device_puts() -> int:
+    """Programs :func:`make_program` has put on the device so far (new
+    contents; a content still kept is returned without a put)."""
+    return _INSTALLED.puts
+
+
+def make_program(offsets, epoch, live,
+                 rank_epoch: Optional[np.ndarray] = None) -> RouteProgram:
+    """The device RouteProgram of these host arrays (``rank_epoch``
+    defaults to the flat broadcast of ``epoch``): the one already on the
+    device when its content is, else a new one."""
     if rank_epoch is None:
         rank_epoch = _rank_epoch_from(np.asarray(epoch, np.int64),
                                       np.asarray(live, bool))
-    return RouteProgram(offsets=jnp.asarray(off, jnp.int32),
-                        epoch=jnp.asarray(epoch, jnp.int32),
-                        live=jnp.asarray(live, bool),
-                        rank_epoch=jnp.asarray(rank_epoch, jnp.int32))
+    return _INSTALLED.get(_host_program(offsets, epoch, live, rank_epoch))
+
+
+def _pack_epochs(off: np.ndarray, live: np.ndarray) -> np.ndarray:
+    """Flat base epochs: per direction, live slots by hop count (stable),
+    one circuit per direction per epoch; -1 on dead slots."""
+    epoch = np.full(off.shape, -1, np.int64)
+    for sign in (1, -1):
+        idx = np.nonzero(live & (np.sign(off) == sign))[0]
+        order = np.argsort(np.abs(off[idx]), kind="stable")
+        epoch[idx[order]] = np.arange(len(idx))
+    return epoch
 
 
 def unidirectional_program(num_nodes: int, direction: int = 1) -> RouteProgram:
@@ -184,7 +287,7 @@ def unidirectional_program(num_nodes: int, direction: int = 1) -> RouteProgram:
     d = np.arange(1, num_nodes)
     off = d if direction >= 0 else -(num_nodes - d)
     hops = np.abs(off)
-    return _program(off, hops - 1, np.ones_like(d, bool))
+    return make_program(off, hops - 1, np.ones_like(d, bool))
 
 
 def bidirectional_program(num_nodes: int) -> RouteProgram:
@@ -197,7 +300,7 @@ def bidirectional_program(num_nodes: int) -> RouteProgram:
     d = np.arange(1, num_nodes)
     back = num_nodes - d
     off = np.where(d <= back, d, -back)
-    return _program(off, np.abs(off) - 1, np.ones_like(d, bool))
+    return make_program(off, np.abs(off) - 1, np.ones_like(d, bool))
 
 
 def pruned_program(base: RouteProgram, live_distances) -> RouteProgram:
@@ -217,20 +320,15 @@ def pruned_program(base: RouteProgram, live_distances) -> RouteProgram:
         if not 0 < d < n:
             raise ValueError(f"distance {d} out of range for {n} nodes")
         keep[d - 1] = True
-    re = np.asarray(base.rank_epoch)
+    host = base.on_host()
+    re = host.rank_epoch
     flat = (re == re[:, :1]).all()  # every row uniform = no group mask
     if not flat:
         return masked_ranks_program(base, np.broadcast_to(keep[:, None],
                                                           re.shape))
-    off = np.asarray(base.offsets).copy()
-    live = np.asarray(base.live) & keep
-    off = np.where(live, off, 0)
-    epoch = np.full((n - 1,), -1, np.int64)
-    for sign in (1, -1):
-        idx = np.nonzero(live & (np.sign(off) == sign))[0]
-        order = np.argsort(np.abs(off[idx]), kind="stable")
-        epoch[idx[order]] = np.arange(len(idx))
-    return _program(off, epoch, live)
+    live = host.live & keep
+    off = np.where(live, host.offsets, 0)
+    return make_program(off, _pack_epochs(off, live), live)
 
 
 def load_balanced_program(num_nodes: int, dist_weight,
@@ -245,10 +343,11 @@ def load_balanced_program(num_nodes: int, dist_weight,
     ``perfmodel.predict_round_latency_us`` models): instead of the static
     shortest-way split (min(d, N-d)), distances are partitioned greedily —
     heaviest first, each onto the currently lighter direction (ties prefer
-    fewer hops).  Zero-weight distances are pruned (``prune=True``) or kept
-    on their shortest-way direction as free riders.  Epochs compact per
-    direction, shortest hop count first, one circuit per direction per
-    epoch.
+    fewer hops).  Greedy is not optimal: where its bottleneck comes out
+    larger than the shortest-way split's, the shortest-way split is kept.
+    Zero-weight distances are pruned (``prune=True``) or kept on their
+    shortest-way direction as free riders.  Epochs compact per direction,
+    shortest hop count first, one circuit per direction per epoch.
     """
     n = num_nodes
     w = np.asarray(dist_weight, float).reshape(-1)
@@ -258,29 +357,26 @@ def load_balanced_program(num_nodes: int, dist_weight,
     if (w < 0).any():
         raise ValueError("dist_weight must be non-negative")
     live = (w > 0) if prune else np.ones((n - 1,), bool)
-    off = np.zeros((n - 1,), np.int64)
+    d = np.arange(1, n)
+    shortest = np.where(live, np.where(d <= n - d, d, -(n - d)), 0)
+    off = shortest.copy()
     loads = {1: 0.0, -1: 0.0}
-    order = sorted(np.nonzero(live & (w > 0))[0].tolist(),
-                   key=lambda k: (-w[k], k))
-    for k in order:
-        d = k + 1
+    for k in sorted(np.nonzero(w > 0)[0].tolist(), key=lambda k: (-w[k], k)):
         if loads[1] < loads[-1]:
             sign = 1
         elif loads[-1] < loads[1]:
             sign = -1
         else:
-            sign = 1 if d <= n - d else -1
-        off[k] = d if sign == 1 else -(n - d)
+            sign = int(np.sign(shortest[k]))
+        off[k] = d[k] if sign == 1 else -(n - d[k])
         loads[sign] += w[k]
-    for k in np.nonzero(live & (w == 0))[0]:
-        d = k + 1
-        off[k] = d if d <= n - d else -(n - d)
-    epoch = np.full((n - 1,), -1, np.int64)
-    for sign in (1, -1):
-        idx = np.nonzero(live & (np.sign(off) == sign))[0]
-        order2 = np.argsort(np.abs(off[idx]), kind="stable")
-        epoch[idx[order2]] = np.arange(len(idx))
-    return _program(off, epoch, live)
+
+    def bottleneck(o):
+        return max(w[o > 0].sum(), w[o < 0].sum())
+
+    if bottleneck(off) > bottleneck(shortest):
+        off = shortest
+    return make_program(off, _pack_epochs(off, live), live)
 
 
 def link_avoiding_program(num_nodes: int, failed_direction: int
@@ -434,7 +530,7 @@ def hierarchical_program(topo: Topology, dist_weight=None, prune: bool = False,
                               ).min(1), -1)
     live = live & (rank_epoch >= 0).any(1)
     off = np.where(live, off, 0)
-    return _program(off, epoch, live, rank_epoch)
+    return make_program(off, epoch, live, rank_epoch)
 
 
 def masked_ranks_program(base: RouteProgram, rank_live) -> RouteProgram:
@@ -452,16 +548,17 @@ def masked_ranks_program(base: RouteProgram, rank_live) -> RouteProgram:
     # int64 up-cast: the stored rank_epoch is int32, and the int64 max
     # sentinel below would wrap to -1 in that dtype, zeroing every
     # surviving slot's base epoch (caught by bridgelint PC106).
-    re = np.asarray(base.rank_epoch, np.int64)
+    host = base.on_host()
+    re = host.rank_epoch.astype(np.int64)
     if rank_live.shape != re.shape:
         raise ValueError(f"rank_live has shape {rank_live.shape}; program "
                          f"has {re.shape}")
     re = np.where(rank_live, re, -1)
-    live = np.asarray(base.live) & (re >= 0).any(1)
-    off = np.where(live, np.asarray(base.offsets), 0)
+    live = host.live & (re >= 0).any(1)
+    off = np.where(live, host.offsets, 0)
     epoch = np.where(live,
                      np.where(re >= 0, re, np.iinfo(np.int64).max).min(1), -1)
-    return _program(off, epoch, live, re)
+    return make_program(off, epoch, live, re)
 
 
 def validate_hierarchical(program: RouteProgram, topo: Topology) -> None:

@@ -101,7 +101,7 @@ def _jsonable(v):
 
 # ----------------------------------------------------------- program serde
 #: (field, numpy dtype) normalization used by both the digest and the
-#: JSON round-trip — matches steering._program's construction dtypes.
+#: JSON round-trip — matches ``steering.PROGRAM_FIELDS``, the device dtypes.
 _PROGRAM_FIELDS = (("offsets", np.int32), ("epoch", np.int32),
                    ("live", np.bool_), ("rank_epoch", np.int32))
 
@@ -114,15 +114,9 @@ def program_to_dict(program) -> Dict[str, Any]:
 
 def program_from_dict(d: Dict[str, Any]):
     """Rebuild a RouteProgram with the canonical jnp dtypes."""
-    import jax.numpy as jnp
+    from repro.core.steering import make_program
 
-    from repro.core.steering import RouteProgram
-
-    return RouteProgram(
-        offsets=jnp.asarray(d["offsets"], jnp.int32),
-        epoch=jnp.asarray(d["epoch"], jnp.int32),
-        live=jnp.asarray(d["live"], bool),
-        rank_epoch=jnp.asarray(d["rank_epoch"], jnp.int32))
+    return make_program(d["offsets"], d["epoch"], d["live"], d["rank_epoch"])
 
 
 def program_digest(program) -> str:

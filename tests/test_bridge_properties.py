@@ -47,7 +47,9 @@ def test_pull_property_random_requests(num_logical, budget, seed):
 @settings(max_examples=20, deadline=None)
 @given(seed=st.integers(0, 10_000), nodes=st.integers(1, 6))
 def test_control_plane_invariants(seed, nodes):
-    """No slot double-booked; every mapped page has a live home."""
+    """No slot double-booked; every mapped page has a live home; the
+    occupancy is the plain count, after random allocate / fail_node /
+    release."""
     rng = np.random.default_rng(seed)
     cp = ControlPlane(num_nodes=nodes, pages_per_node=8, num_logical=64)
     regions = []
@@ -63,12 +65,17 @@ def test_control_plane_invariants(seed, nodes):
             ["striped", "hashed"]))))
     if nodes > 1 and rng.random() < 0.5:
         cp.fail_node(int(rng.integers(0, nodes)))
+    if regions and rng.random() < 0.5:
+        cp.release(regions.pop(int(rng.integers(len(regions)))))
     home, slot = np.asarray(cp._home), np.asarray(cp._slot)
     mapped = home != FREE
     pairs = set(zip(home[mapped].tolist(), slot[mapped].tolist()))
     assert len(pairs) == mapped.sum(), "slot double-booked"
     for h in home[mapped]:
         assert cp.nodes[h].alive, "page homed on dead node"
+    # occupancy() is a plain count of the homed pages per node
+    plain = [sum(1 for h in home if h == n) for n in range(nodes)]
+    assert cp.occupancy().tolist() == plain
 
 
 @settings(max_examples=20, deadline=None)

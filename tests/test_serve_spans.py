@@ -2,8 +2,9 @@
 engine record into a ``TraceRecorder`` from inside the program.
 
 * the span tree under a ``ManualClock``: ``serve.control`` >
-  ``orc.step`` > ``orc.refit`` > ``cp.route_program`` > ``cp.verify`` /
-  ``cp.journal`` exactly every ``control_period`` ticks, admission and
+  ``orc.step`` > ``orc.refit`` > ``cp.route_program`` > ``cp.verify``
+  (a content's first time only) / ``cp.journal`` exactly every
+  ``control_period`` ticks, admission and
   retirement spans, one ``req.queued`` per admission,
 * ``engine.step`` > ``engine.reset`` / ``engine.dispatch`` /
   ``engine.fetch`` on a tiny jitted model,
@@ -80,12 +81,25 @@ def test_span_tree_of_the_control_tick():
     assert len(refits) == bat.step_count // PERIOD
     routes = rec.find_all(CP + "route_program")
     assert len(routes) == len(refits) + 1 and routes[0].parent_id is None
+    # A program whose content this plane already verified is neither put on
+    # the device nor verified again: its span says reused, with no verify.
+    digests = [r.detail["digest"]
+               for r in bat.orc.flight.records("route_program")]
+    assert len(digests) == len(routes)
+    for i, route in enumerate(routes):
+        assert route.args["reused"] == (digests[i] in digests[:i])
+    assert not routes[0].args["reused"]
+    assert any(s.args["reused"] for s in routes)
     for r in refits:
         (route,) = [s for s in rec.children(r)
                     if s.name == CP + "route_program"]
-        assert _kids(rec, route) == [CP + "verify", CP + "journal"]
+        verify = [] if route.args["reused"] else [CP + "verify"]
+        assert _kids(rec, route) == verify + [CP + "journal"]
         assert route.start_us <= rec.children(route)[0].start_us
         assert rec.children(route)[-1].end_us <= route.end_us <= r.end_us
+    counts = bat.orc.cp.route_counts
+    assert counts.verified == len(rec.find_all(CP + "verify"))
+    assert counts.verified + counts.verify_skipped == len(routes)
 
     # One orc.request_lease per admission attempt, all inside serve.admit;
     # each attempt journals one admission verdict for its request.
