@@ -67,20 +67,71 @@ class WallClock:
 
 
 def program_config(cfg: Dict[str, Any]):
-    """The program's ``ModelConfig`` with the file's sizes as run."""
+    """The program's ``ModelConfig`` with the file's sizes as run, built
+    from the file's per-layer plan (``harness.plan``).  Raises
+    ``plan.PlanError``, naming the key, for a structure the program
+    cannot build."""
     from repro import configs
+    from repro.config import FULL_ATTN, SWA_ATTN
+
+    from harness import plan as pl
+    layers = pl.layer_plan(cfg)
     base = configs.get_config(cfg["deployment"]["registry"])
-    d = int(cfg["hidden_size"])
-    h = int(cfg["num_attention_heads"])
+    heads = pl.uniform(layers, lambda x: x.attn.heads,
+                       "num_attention_heads", "head count")
+    kv = pl.uniform(layers, lambda x: x.attn.kv_heads,
+                    "num_key_value_heads", "KV head count")
+    window = pl.uniform([x for x in layers if x.attn.kind == pl.WINDOW],
+                        lambda x: x.attn.window, "sliding_window", "window")
+    kinds = tuple(SWA_ATTN if x.attn.kind == pl.WINDOW else FULL_ATTN
+                  for x in layers)
+    mixed = ("mlp_layer_types" if "mlp_layer_types" in cfg
+             else "num_dense_layers")
+    ffn = pl.uniform(layers, lambda x: x.ffn, mixed,
+                     "feed-forward block (dense or routed)")
+    moe = {"num_experts": 0, "experts_per_token": 0}
+    if ffn.kind == pl.ROUTED:
+        _routable(cfg, ffn)
+        moe = {"family": "moe", "num_experts": ffn.experts_held,
+               "experts_per_token": ffn.experts_per_token}
     return dataclasses.replace(
-        base, num_layers=int(cfg["num_hidden_layers"]), d_model=d,
-        num_heads=h, num_kv_heads=int(cfg["num_key_value_heads"]),
-        head_dim=int(cfg.get("head_dim", d // h)),
-        d_ff=int(cfg["intermediate_size"]),
+        base, num_layers=len(layers), d_model=int(cfg["hidden_size"]),
+        num_heads=heads, num_kv_heads=kv,
+        head_dim=pl.uniform(layers, lambda x: x.attn.head_dim, "head_dim",
+                            "head size"),
+        d_ff=ffn.width, layer_pattern=pl.period(kinds),
+        window_size=window or 0, **moe,
         vocab_size=int(cfg["vocab_size"]),
         rope_theta=float(cfg["rope_theta"]),
         tie_embeddings=bool(cfg["tie_word_embeddings"]),
         dtype=cfg["torch_dtype"])
+
+
+def _routable(cfg: Dict[str, Any], ffn) -> None:
+    """Refuse a routed block that ``repro.models.moe`` cannot build: it
+    holds every expert its router scores, renormalises softmax gates and
+    has no shared expert."""
+    from harness.plan import PlanError
+    if ffn.router_width != ffn.experts_held:
+        raise PlanError("num_experts", f"the router scores "
+                        f"{ffn.router_width} experts and {ffn.experts_held} "
+                        f"are held here; the program's expert layer holds "
+                        f"every expert it routes to")
+    if ffn.shared_width:
+        key = ("shared_expert_intermediate_size"
+               if "shared_expert_intermediate_size" in cfg
+               else "num_shared_experts")
+        raise PlanError(key, "the program's expert layer has no shared "
+                        "expert")
+    if not ffn.norm_topk_prob:
+        raise PlanError("norm_topk_prob", "the program always renormalises "
+                        "the chosen gates")
+    if ffn.score_func != "softmax":
+        raise PlanError("score_func", f"the program routes by softmax, not "
+                        f"{ffn.score_func!r}")
+    if ffn.route_scale != 1.0:
+        raise PlanError("route_scale", "the program does not scale the "
+                        "routed output")
 
 
 def build_server(cfg: Dict[str, Any], mix: Dict[str, Any], params,

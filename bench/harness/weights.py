@@ -16,6 +16,12 @@ step reads (``repro.models.transformer.abstract_params``);
 :func:`neutral` gives the reference the same arrays under plain names.
 The reference calls the same compiled function, so both see identical
 values.
+
+The tree is walked from the program's own (``resolve`` puts it in the
+configuration as ``_program_params``), so a routed block (``moe``), a
+period of several layer kinds (``periods/pos{i}``) or an unscanned
+``tail`` needs no code here; a leaf the harness has no stream id for is
+an error.
 """
 from __future__ import annotations
 
@@ -24,11 +30,20 @@ from typing import Any, Dict, Tuple
 
 import numpy as np
 
-# Fixed stream ids: a leaf's values do not depend on which other leaves
-# exist.
+# Fixed stream ids, by the leaf's name in the program's tree (a block's
+# leaves by their path inside the block): a leaf's values do not depend on
+# which other leaves exist.
 _LEAF_IDS = {"embed": 1, "lm_head": 2, "out_norm": 3, "norm1": 4,
-             "norm2": 5, "wq": 6, "wk": 7, "wv": 8, "wo": 9, "w_in": 10,
-             "w_gate": 11, "w_out": 12}
+             "norm2": 5, "attn.wq": 6, "attn.wk": 7, "attn.wv": 8,
+             "attn.wo": 9, "ffn.wi": 10, "ffn.wg": 11, "ffn.wo": 12,
+             "moe.router": 13, "moe.wi": 14, "moe.wg": 15, "moe.wo": 16}
+# The reference's names for a block's leaves.
+_PLAIN = {"norm1": "norm1", "norm2": "norm2", "attn.wq": "wq",
+          "attn.wk": "wk", "attn.wv": "wv", "attn.wo": "wo",
+          "ffn.wi": "w_in", "ffn.wg": "w_gate", "ffn.wo": "w_out",
+          "moe.router": "router", "moe.wi": "expert_in",
+          "moe.wg": "expert_gate", "moe.wo": "expert_out"}
+_NORMS = ("norm1", "norm2", "out_norm")
 
 
 def seed_words(seed: int) -> np.ndarray:
@@ -40,86 +55,103 @@ def seed_words(seed: int) -> np.ndarray:
                     + [neg], np.uint32)
 
 
-def sizes_of(cfg: Dict[str, Any]) -> Tuple[Tuple[str, Any], ...]:
-    """The hashable sizes of a configuration file, for the jit's key."""
-    d = int(cfg["hidden_size"])
-    h = int(cfg["num_attention_heads"])
-    return (("d", d), ("heads", h), ("kv", int(cfg["num_key_value_heads"])),
-            ("hd", int(cfg.get("head_dim", d // h))),
-            ("ff", int(cfg["intermediate_size"])),
-            ("vocab", int(cfg["vocab_size"])),
-            ("padded_vocab", int(cfg["padded_vocab"])),
-            ("layers", int(cfg["num_hidden_layers"])),
-            ("glu", bool(cfg["glu"])),
-            ("tied", bool(cfg["tie_word_embeddings"])))
+def _leaf_name(path: Tuple[str, ...]) -> str:
+    """``periods/pos0/attn/wq`` -> ``attn.wq``; ``embed`` -> ``embed``."""
+    inner = path[2:] if path[0] in ("periods", "tail") else path
+    name = ".".join(inner)
+    if name not in _LEAF_IDS:
+        raise ValueError(f"the program's weight {'/'.join(path)} has no "
+                         f"stream id in the harness")
+    return name
 
 
-def leaf_specs(s: Dict[str, Any]) -> Dict[str, Tuple[tuple, str, float]]:
-    """name -> (shape, kind, std); ``kind`` is ``normal`` or ``norm``."""
-    d, h, kv, hd, ff = s["d"], s["heads"], s["kv"], s["hd"], s["ff"]
-    L, V = s["layers"], s["vocab"]
-    spec = {
-        "embed": ((V, d), "normal", 1.0 / d),
-        "out_norm": ((d,), "norm", 0.0),
-        "norm1": ((L, d), "norm", 0.0),
-        "norm2": ((L, d), "norm", 0.0),
-        "wq": ((L, d, h * hd), "normal", d ** -0.5),
-        "wk": ((L, d, kv * hd), "normal", d ** -0.5),
-        "wv": ((L, d, kv * hd), "normal", d ** -0.5),
-        "wo": ((L, h * hd, d), "normal", (h * hd) ** -0.5),
-        "w_in": ((L, d, ff), "normal", d ** -0.5),
-        "w_out": ((L, ff, d), "normal", ff ** -0.5),
-    }
-    if s["glu"]:
-        spec["w_gate"] = ((L, d, ff), "normal", d ** -0.5)
-    if not s["tied"]:
-        spec["lm_head"] = ((V, d), "normal", d ** -0.5)
-    return spec
+def leaf_specs(cfg: Dict[str, Any]) -> Tuple[tuple, ...]:
+    """How each leaf of the program's weight tree is drawn, hashable for
+    the jit's key: ``(path, shape, kind, std, stream id, layers, draw)``.
+
+    ``kind`` is ``normal`` or ``norm``; ``std`` is ``fan_in ** -0.5``
+    along the contraction axis (the embedding ``1 / hidden``).  ``layers``
+    is ``None`` for the embedding, head and final norm; otherwise the
+    layer numbers the leaf holds, one per row of a stacked leaf
+    (``periods/pos{i}``: layers ``i, i + period, ...``) or one for an
+    unstacked ``tail`` leaf.  ``draw`` is ``(total layers, rows drawn
+    for the embedding and head)``.
+    """
+    import jax
+    tree = cfg["_program_params"]
+    total = int(cfg["num_hidden_layers"])
+    vocab = int(cfg["vocab_size"])
+    hidden = int(cfg["hidden_size"])
+    periods = tree.get("periods", {})
+    n_per = len(periods)
+    reps = (jax.tree.leaves(periods)[0].shape[0] if periods else 0)
+    out = []
+    for kp, leaf in jax.tree_util.tree_flatten_with_path(tree)[0]:
+        path = tuple(k.key for k in kp)
+        name = _leaf_name(path)
+        shape = tuple(int(x) for x in leaf.shape)
+        if path[0] == "periods":
+            i = int(path[1][len("pos"):])
+            layers = tuple(i + r * n_per for r in range(reps))
+        elif path[0] == "tail":
+            layers = (reps * n_per + int(path[1][len("layer"):]),)
+        else:
+            layers = None
+        if name in _NORMS:
+            kind, std = "norm", 0.0
+        elif name == "embed":
+            kind, std = "normal", 1.0 / hidden
+        elif name == "lm_head":
+            kind, std = "normal", hidden ** -0.5
+        else:
+            kind, std = "normal", shape[-2] ** -0.5
+        out.append((path, shape, kind, std, _LEAF_IDS[name], layers,
+                    (total, vocab)))
+    return tuple(out)
 
 
-def _leaf(key, shape, kind, std, stacked):
+def _slab(key, shape, kind, std):
     import jax
     import jax.numpy as jnp
-
-    def slab(k, shp):
-        z = jax.random.normal(k, shp, jnp.float32)
-        if kind == "norm":
-            return (1.0 + 0.1 * z).astype(jnp.bfloat16)
-        return (z * std).astype(jnp.bfloat16)
-
-    if not stacked:
-        return slab(key, shape)
-    # One layer at a time: the float32 draw of a whole stack never lives.
-    keys = jax.random.split(key, shape[0])
-    return jax.lax.map(lambda k: slab(k, shape[1:]), keys)
+    z = jax.random.normal(key, shape, jnp.float32)
+    if kind == "norm":
+        return (1.0 + 0.1 * z).astype(jnp.bfloat16)
+    return (z * std).astype(jnp.bfloat16)
 
 
-def _make(sizes, words):
+def _leaf(key, spec):
     import jax
     import jax.numpy as jnp
+    path, shape, kind, std, _, layers, (total, vocab) = spec
+    if layers is None:
+        if path[0] in ("embed", "lm_head"):
+            # drawn at the vocabulary's size; the padding rows are zero
+            a = _slab(key, (vocab,) + shape[1:], kind, std)
+            pad = shape[0] - vocab
+            return jnp.pad(a, ((0, pad), (0, 0))) if pad else a
+        return _slab(key, shape, kind, std)
+    # Layer l's slab is drawn from the l-th of ``total`` keys, whichever
+    # stack holds it; one layer at a time, so the float32 draw of a whole
+    # stack never lives.
+    keys = jax.random.split(key, total)
+    if path[0] == "tail":
+        return _slab(keys[layers[0]], shape, kind, std)
+    if layers != tuple(range(total)):
+        keys = keys[np.asarray(layers)]
+    return jax.lax.map(lambda k: _slab(k, shape[1:], kind, std), keys)
 
-    s = dict(sizes)
+
+def _make(specs, words):
+    import jax
     key = jax.random.key(0)
     for i in range(4):
         key = jax.random.fold_in(key, words[i])
-    w = {name: _leaf(jax.random.fold_in(key, _LEAF_IDS[name]), shape, kind,
-                     std, stacked=name not in ("embed", "lm_head",
-                                               "out_norm"))
-         for name, (shape, kind, std) in leaf_specs(s).items()}
-    pad = s["padded_vocab"] - s["vocab"]
-
-    def rows(a):
-        return jnp.pad(a, ((0, pad), (0, 0))) if pad else a
-
-    ffn = {"wi": w["w_in"], "wo": w["w_out"]}
-    if s["glu"]:
-        ffn["wg"] = w["w_gate"]
-    tree = {"embed": rows(w["embed"]), "out_norm": w["out_norm"],
-            "periods": {"pos0": {
-                "norm1": w["norm1"], "norm2": w["norm2"], "ffn": ffn,
-                "attn": {k: w[k] for k in ("wq", "wk", "wv", "wo")}}}}
-    if not s["tied"]:
-        tree["lm_head"] = rows(w["lm_head"])
+    tree: Dict[str, Any] = {}
+    for spec in specs:
+        node = tree
+        for k in spec[0][:-1]:
+            node = node.setdefault(k, {})
+        node[spec[0][-1]] = _leaf(jax.random.fold_in(key, spec[4]), spec)
     return tree
 
 
@@ -130,26 +162,48 @@ def _jitted():
 
 
 def make_weights(cfg: Dict[str, Any], seed: int):
-    """The served weights of ``cfg`` for ``seed``: one jitted call."""
-    return _jitted()(sizes_of(cfg), seed_words(seed))
+    """The served weights of ``cfg`` (as :func:`bench.run.resolve` gives
+    it) for ``seed``: one jitted call."""
+    return _jitted()(leaf_specs(cfg), seed_words(seed))
 
 
 def abstract_weights(cfg: Dict[str, Any]):
     import jax
-    return jax.eval_shape(functools.partial(_make, sizes_of(cfg)),
+    return jax.eval_shape(functools.partial(_make, leaf_specs(cfg)),
                           seed_words(0))
 
 
+def _plain(block: Dict[str, Any]) -> Dict[str, Any]:
+    out = {}
+    for k, v in block.items():
+        if isinstance(v, dict):
+            out.update({_PLAIN[f"{k}.{kk}"]: vv for kk, vv in v.items()})
+        else:
+            out[_PLAIN[k]] = v
+    return out
+
+
 def neutral(tree) -> Dict[str, Any]:
-    """The reference's view of :func:`make_weights`'s tree (no copies;
-    the embedding and head keep their padding rows)."""
-    p = tree["periods"]["pos0"]
-    out = {"embed": tree["embed"], "out_norm": tree["out_norm"],
-           "norm1": p["norm1"], "norm2": p["norm2"],
-           "w_in": p["ffn"]["wi"], "w_out": p["ffn"]["wo"]}
-    out.update(p["attn"])
-    if "wg" in p["ffn"]:
-        out["w_gate"] = p["ffn"]["wg"]
-    if "lm_head" in tree:
-        out["lm_head"] = tree["lm_head"]
+    """The reference's view of :func:`make_weights`'s tree, under plain
+    names and without copies (the embedding and head keep their padding
+    rows).
+
+    Where one stack holds every layer (``periods/pos0`` and no tail), a
+    block's leaves are given flat, stacked by layer.  Otherwise
+    ``layers`` lists, in layer order, each layer's ``(leaves, row)``: the
+    leaves of the stack that holds it and its row there (``None`` for an
+    unstacked tail layer).
+    """
+    out = {k: tree[k] for k in ("embed", "out_norm", "lm_head")
+           if k in tree}
+    periods, tail = tree.get("periods", {}), tree.get("tail", {})
+    if len(periods) == 1 and not tail:
+        out.update(_plain(periods["pos0"]))
+        return out
+    stacks = [_plain(periods[f"pos{i}"]) for i in range(len(periods))]
+    reps = len(next(iter(stacks[0].values()))) if stacks else 0
+    layers = [(stacks[i], r) for r in range(reps)
+              for i in range(len(stacks))]
+    layers += [(_plain(tail[f"layer{i}"]), None) for i in range(len(tail))]
+    out["layers"] = tuple(layers)
     return out

@@ -82,21 +82,55 @@ def devices(chips: int, require_chip: bool):
 
 
 def resolve(cell: spec.Cell) -> Dict[str, Any]:
-    """The configuration file plus what the harness derives from it."""
-    from harness import serve
+    """The configuration file plus what the harness derives from it.
+    Whatever the program cannot build is refused here, before any weight
+    is made, by a ``plan.PlanError`` that names the file's key."""
+    from harness import plan, serve
     from repro.models import transformer
     cfg = dict(cell.config)
     arch = cell.reference().arch(cfg)
     cfg["glu"] = arch["glu"]
+    layers = plan.layer_plan(cfg)
     model = serve.program_config(cfg)
     cfg["padded_vocab"] = model.padded_vocab
     want = {"rms": "rmsnorm", "layer": "layernorm"}[arch["norm"]]
-    if model.norm != want or model.glu != arch["glu"] or \
-            model.family != "dense" or tuple(model.layer_pattern) != ("full",):
+    if model.norm != want or model.glu != arch["glu"]:
         raise ValueError(f"{cfg['deployment']['registry']}: the program's "
                          f"blocks differ from the reference's {arch}")
+    check_plan(model, layers)
     cfg["_program_params"] = transformer.abstract_params(model)
     return cfg
+
+
+def check_plan(model, layers) -> None:
+    """The program's layers, one by one, are the plan's."""
+    from repro.config import FULL_ATTN, GLOBAL_ATTN, SWA_ATTN
+
+    from harness import plan
+    if len(model.layers) != len(layers):
+        raise ValueError(f"the program has {len(model.layers)} layers, the "
+                         f"plan {len(layers)}")
+    for i, (kind, want) in enumerate(zip(model.layers, layers)):
+        a, f = want.attn, want.ffn
+        if a.kind == plan.WINDOW:
+            attn_ok = kind == SWA_ATTN and model.window_size == a.window
+        else:
+            attn_ok = kind in (FULL_ATTN, GLOBAL_ATTN)
+        attn_ok = attn_ok and (model.num_heads, model.num_kv_heads,
+                               model.head_dim) == (a.heads, a.kv_heads,
+                                                   a.head_dim)
+        if f.kind == plan.ROUTED:
+            ffn_ok = model.is_moe and (
+                model.num_experts, model.experts_per_token, model.d_ff) == (
+                f.experts_held, f.experts_per_token, f.width)
+        else:
+            ffn_ok = not model.is_moe and model.d_ff == f.width
+        if not (attn_ok and ffn_ok):
+            raise ValueError(
+                f"layer {i}: the program builds {kind} attention "
+                f"(window {model.window_size}) and "
+                f"{'routed' if model.is_moe else 'dense'} d_ff {model.d_ff}; "
+                f"the plan says {want}")
 
 
 def check_layout(cfg: Dict[str, Any]) -> None:
